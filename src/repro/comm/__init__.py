@@ -18,10 +18,8 @@ every :class:`repro.core.compressors.Compressor`:
     decode-and-average gathered payloads, per-bucket wire/density accounting.
 ``collective``
     The strategy semantics, run under **fully-manual** ``shard_map`` over
-    every mesh axis so jax 0.4.37's partial-manual ``IsManualSubgroup`` abort
-    is never reachable (collectives over a manual subgroup while other axes
-    stay auto is exactly the broken configuration; see
-    tests/test_distributed.py).
+    every mesh axis: buckets keep no intra-leaf sharding, so no axis needs
+    to stay GSPMD-auto.
 ``exchange``
     :class:`PayloadStack` — the slot-native view every backend returns from
     its exchange: read ``.mean()`` (fused fast path where the transport has

@@ -24,10 +24,13 @@ from __future__ import annotations
 import dataclasses
 from typing import TYPE_CHECKING
 
+import jax
+
 from repro.comm import bucketize, compressed, robust
 from repro.comm.errors import PathConfigError, UnknownStrategyError, WireFormatError
 from repro.configs.base import ByzConfig, OverlapConfig
 from repro.core.compressors import Compressor, ScaledSignCompressor, get_compressor
+from repro.kernels import ops
 
 if TYPE_CHECKING:  # repro.fed imports comm.errors — keep the runtime edge one-way
     from repro.fed.spec import FedSpec
@@ -157,6 +160,18 @@ class CommSpec:
                     "compute; the fed round is a server-side simulation with no "
                     "collective to hide — drop the overlap rider"
                 )
+        if (
+            self.strategy in collective.EF_STRATEGIES
+            and self.bucket_size is not None
+            and compressed.is_sign(comp)
+            and jax.default_backend() == "tpu"
+            and self.bucket_size % ops.BUCKET_PALLAS_MULTIPLE
+        ):
+            raise WireFormatError(
+                f"on a TPU the sign wire format runs the Pallas bucket kernels, "
+                f"which need bucket_size % {ops.BUCKET_PALLAS_MULTIPLE} == 0; got "
+                f"{self.bucket_size}"
+            )
         if ef_axes is not None and self.strategy == "ef_ring":
             backends.ring_axis(ef_axes)  # single-axis EF world required
         if world is not None:

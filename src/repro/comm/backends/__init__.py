@@ -12,7 +12,7 @@ strategy semantics can ride three transports, selected per mesh —
                 hops are ``make_async_remote_copy`` issued in-kernel and
                 both readings stay in the compressed domain — no dense
                 per-worker gradient ever lands in HBM. Needs a real TPU
-                ring; :func:`resolve` substitutes ``ring`` elsewhere
+                ring; on the CPU :func:`resolve` substitutes ``ring``
                 (bitwise-equal result) and logs the reason.
 
 Every backend exchanges payloads into one slot-native
@@ -113,8 +113,9 @@ def resolve(spec, mesh, ef_axes=(), *, layout=None) -> CollectiveBackend:
     """Pick the backend instance for ``spec`` on ``mesh``.
 
     ``backend="auto"`` is deterministic per mesh (see module docstring);
-    an explicit ``pallas_dma`` off-TPU degrades to ``ring`` with a logged
-    reason rather than failing, so one spec serves CI and hardware. The
+    an explicit ``pallas_dma`` on the CPU degrades to ``ring`` with a logged
+    reason (bitwise-equal result), so one spec serves CPU tests and
+    hardware; on any other non-TPU backend it raises. The
     returned backend has passed its capability check for this spec.
     """
     name = spec.backend or "auto"
@@ -122,6 +123,11 @@ def resolve(spec, mesh, ef_axes=(), *, layout=None) -> CollectiveBackend:
         name = _auto_backend(spec, mesh, ef_axes, layout)
     be = lookup(name)
     if name == "pallas_dma" and not BACKENDS["pallas_dma"].available():
+        if jax.default_backend() != "cpu":
+            raise BackendCapabilityError(
+                "backend 'pallas_dma' needs the TPU remote-DMA ring; the jax "
+                f"backend here is {jax.default_backend()!r}"
+            )
         logger.warning(
             "backend 'pallas_dma' needs the TPU remote-DMA ring (jax backend "
             "is %r here); falling back to the 'ring' backend — same W-1 hop "
@@ -144,7 +150,7 @@ def capability_matrix(mesh, ef_axes: tuple[str, ...] = ("data",), comp=None) -> 
 
     Returns ``{strategy: {backend: cell}}`` where a cell is ``"ok"``,
     ``"ok (degrades to 'ring' here)"`` for an unavailable ``pallas_dma``
-    that :func:`resolve` would substitute, or ``"-- <reason>"`` quoting the
+    that :func:`resolve` would substitute (CPU only), or ``"-- <reason>"`` quoting the
     :class:`~repro.comm.errors.CommSpecError` the combination raises.
     ``comp=None`` probes each strategy's default (sign) wire format. Used by
     ``launch/dryrun.py`` to surface misconfigurations before compile.
@@ -163,7 +169,10 @@ def capability_matrix(mesh, ef_axes: tuple[str, ...] = ("data",), comp=None) -> 
                 row[name] = f"-- {e}"
             else:
                 if name == "pallas_dma" and not be.available():
-                    row[name] = "ok (degrades to 'ring' here)"
+                    if jax.default_backend() == "cpu":
+                        row[name] = "ok (degrades to 'ring' here)"
+                    else:
+                        row[name] = "-- needs the TPU remote-DMA ring"
                 else:
                     row[name] = "ok"
         out[strategy] = row

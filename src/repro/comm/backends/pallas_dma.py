@@ -4,16 +4,17 @@ Same W−1 hop structure as the ppermute ring, but the hop is a
 ``pltpu.make_async_remote_copy`` issued from inside one Pallas kernel
 (:mod:`repro.kernels.dma_ring`), and both readings of the exchange stay in
 the compressed domain: the mean reading decompress-accumulates straight off
-the compressed slot words in VMEM (the wire never materializes a dense
+the compressed slot words (the wire never materializes a dense
 per-worker gradient in HBM), and the slot reading hands the robust
 strategies the canonical origin-id slots the kernel already gathers —
 ``(W, nb, bs/32)`` words + ``(W, nb)`` scales, 32× smaller than a gradient
 stack. Capability gates:
 
-* needs a real TPU ring — :func:`resolve <repro.comm.backends.resolve>`
-  substitutes the ``ring`` backend off-TPU (same hop structure, same bitwise
-  result for both readings) and logs the reason, so ``backend="pallas_dma"``
-  specs stay portable to CPU CI;
+* needs a real TPU ring — on the CPU :func:`resolve
+  <repro.comm.backends.resolve>` substitutes the ``ring`` backend (same hop
+  structure, same bitwise result for both readings) and logs the reason, so
+  ``backend="pallas_dma"`` specs stay portable to CPU tests; elsewhere it
+  raises;
 * sign wire formats only — the kernel decodes ``words``/``scale`` payloads;
 * single EF axis, like the ppermute ring.
 """

@@ -1,12 +1,8 @@
 """Bucketed gradient collectives under fully-manual ``shard_map``.
 
-Every strategy here runs with **all** mesh axes manual. That is the load-
-bearing design decision: jaxlib 0.4.x's SPMD partitioner aborts
-(``Check failed: sharding.IsManualSubgroup()``) whenever a collective — or
-even a ``lax.scan`` — appears inside a *partial*-manual ``shard_map``, which
-is why the per-leaf EF strategies in ``repro.core.aggregation`` were
-version-keyed xfails. Buckets are dense per-worker stacks with no intra-leaf
-sharding left to preserve, so nothing needs to stay GSPMD-auto: the
+Every strategy here runs with **all** mesh axes manual. Buckets are dense
+per-worker stacks with no intra-leaf sharding left to preserve, so nothing
+needs to stay GSPMD-auto: the
 aggregator body sees its worker's ``(n_buckets, bucket_size)`` slice, runs
 per-bucket compression + EF, and exchanges fixed-size payloads with plain
 manual collectives. Devices that share a worker (model-parallel replicas)
@@ -69,8 +65,8 @@ from repro.utils import compat
 
 AxisNames = tuple[str, ...]
 
-_EF_STRATEGIES = ("ef_allgather", "ef_ring", "ef_alltoall") + robust.ROBUST_STRATEGIES
-STRATEGIES = ("dense",) + _EF_STRATEGIES + ("majority_vote",)
+EF_STRATEGIES = ("ef_allgather", "ef_ring", "ef_alltoall") + robust.ROBUST_STRATEGIES
+STRATEGIES = ("dense",) + EF_STRATEGIES + ("majority_vote",)
 
 
 def world_size(mesh, ef_axes: AxisNames) -> int:
@@ -84,8 +80,7 @@ def _worker_index(ef_axes: AxisNames) -> jax.Array:
     """Linearized index of this device's EF worker (row-major over ef_axes)."""
     idx = jnp.int32(0)
     for a in ef_axes:
-        size = lax.psum(1, a)  # static on both jax dialects
-        idx = idx * size + lax.axis_index(a)
+        idx = idx * lax.axis_size(a) + lax.axis_index(a)
     return idx
 
 
@@ -177,12 +172,14 @@ def build_bucketed_aggregator(
     w = world_size(mesh, ef_axes)
     bs = layout.bucket_size
     ef = ef_axes if len(ef_axes) != 1 else ef_axes[0]
-    masks = tuple(bucketize.valid_mask(layout, gi) for gi in range(len(layout.groups)))
     bucket_bits = comp.wire_bits(bs)
-    has_err = strategy in _EF_STRATEGIES
+    has_err = strategy in EF_STRATEGIES
     has_srv = strategy == "ef_alltoall"
 
     def body(buckets, err, srv, key):
+        # traced, so the (nb, bs) padding masks fuse into their consumers
+        # instead of riding into the program as full-size constants
+        masks = [bucketize.valid_mask(layout, gi) for gi in range(len(layout.groups))]
         outs, new_errs, new_srvs, dens = [], [], [], []
         wire_bits = 0.0
         # telemetry accumulators — per dtype group bits / residual norms,

@@ -7,7 +7,7 @@ piece that turns the paper's operator into a *distributed systems* feature.
 This is the ``bucket_size=None`` fallback of the gradient-exchange stack: the
 default training path runs the same strategies at fixed-size-BUCKET
 granularity through :mod:`repro.comm` (realistic wire format, fully-manual
-collectives that survive jaxlib 0.4.x). The per-leaf implementations below
+collectives). The per-leaf implementations below
 remain for the giant-model dry-run because they are *sharding-preserving*.
 
 All functions here run **inside** ``shard_map`` over the data-parallel mesh
@@ -258,10 +258,7 @@ class AggState(NamedTuple):
 def _axis_size(axis_names: AxisNames) -> int:
     w = 1
     for a in axis_names:
-        if hasattr(lax, "axis_size"):
-            w = w * lax.axis_size(a)
-        else:  # jax 0.4.x: psum of a Python 1 folds to the static axis size
-            w = w * lax.psum(1, a)
+        w = w * lax.axis_size(a)
     return w
 
 

@@ -8,31 +8,32 @@ but the hop itself is a ``pltpu.make_async_remote_copy`` issued from inside
 one Pallas kernel instead of a ``lax.ppermute`` the XLA scheduler has to
 place. Two kernels, one contract:
 
-1. :func:`dma_ring_gather_slots` — the remote-DMA kernel. Per hop it RDMAs
-   the in-flight compressed payload to the right neighbor (double-buffered
-   send/recv comm slots, per-slot DMA semaphores, neighbor barrier before the
-   first hop) and stores each arrival into its canonical origin-id slot — the
-   exact ``(W, nb, bs/32)`` layout ``lax.all_gather`` would produce, except it
-   is 32× smaller than a gradient stack because it never leaves the wire
-   format.
+1. :func:`dma_ring_gather_slots` — the remote-DMA kernel. It copies its own
+   payload into slot ``my_id``, then per hop RDMAs the slot it received last
+   (its own at the first hop) into the same slot of the right neighbor
+   (per-hop DMA semaphores, neighbor barrier before the first hop). Every
+   arrival lands in its canonical origin-id slot — the exact
+   ``(W, nb, bs/32)`` layout ``lax.all_gather`` would produce, 32× smaller
+   than a gradient stack because it never leaves the wire format. All
+   operands stay in HBM; the DMA engines move them with no VMEM staging.
 2. the fused decompress-mean (``kernels.ops.bucket_decompress_mean``, the
-   existing gridded Pallas kernel) — accumulates ±scale signs straight out of
-   the compressed slot words in VMEM, one bucket block at a time.
+   gridded Pallas kernel) — accumulates ±scale signs straight out of the
+   compressed slot words in VMEM, one bucket block at a time.
 
 So the wire never materializes a dense per-worker gradient in HBM: HBM holds
 only compressed slots (d/8 bytes per worker) and the single (nb, bs) fp32
 mean. Decoding in canonical origin order makes the result bitwise-equal to
 ``ef_allgather`` / ``ef_ring`` on every worker — the replication-safety
 argument of the ppermute ring (see its module docstring) carries over
-verbatim, and the subprocess trajectory tests pin it.
+verbatim.
 
-CPU testability: ``make_async_remote_copy`` needs real TPU interconnect, so
-the multi-device kernel is compile-gated (``@pytest.mark.tpu``). Everything
-around it is oracle-checked everywhere: the hop/arrival schedule and the
-slot-store body have pure-jnp oracles in :mod:`repro.kernels.ref`
-(``dma_ring_slots_ref`` / ``dma_ring_mean_ref``), and the single-worker
-degenerate of the kernel (slot seeding, no DMA) runs in interpret mode on any
-backend — that is what the ``-m pallas`` tier exercises in CI.
+Testability: ``make_async_remote_copy`` needs a real TPU interconnect, so
+the multi-device kernel is compiled for a described v5e ring in
+``tests/test_tpu_compile.py`` and run across chips by ``chip_smoke.py
+--chips 4``. The hop/arrival schedule has a pure-jnp oracle in
+:mod:`repro.kernels.ref` (``dma_ring_slots_ref`` / ``dma_ring_mean_ref``),
+and the single-worker degenerate of the kernel (own-slot copy, no remote
+DMA) runs in interpret mode on any backend.
 """
 
 from __future__ import annotations
@@ -43,11 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:  # TPU-only primitives (remote DMA, semaphores); absent on some builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - exercised only on pallas-less builds
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 AxisNames = tuple[str, ...]
 
@@ -56,27 +53,8 @@ RING_COLLECTIVE_ID = 7
 
 
 def supported() -> bool:
-    """True when the remote-DMA kernel can actually run (TPU + pltpu)."""
-    return pltpu is not None and jax.default_backend() == "tpu"
-
-
-def _compiler_params(collective_id: int):
-    """Version-portable Mosaic params: the kernel has side effects (RDMA into
-    a peer) and participates in a collective."""
-    if hasattr(pltpu, "CompilerParams"):
-        return pltpu.CompilerParams(has_side_effects=True, collective_id=collective_id)
-    if hasattr(pltpu, "TPUCompilerParams"):  # jax 0.4.3x name
-        return pltpu.TPUCompilerParams(has_side_effects=True, collective_id=collective_id)
-    return dict(mosaic=dict(has_side_effects=True, collective_id=collective_id))
-
-
-def _seed_slots_kernel(widx_ref, words_ref, scales_ref, slot_words_ref, slot_scales_ref):
-    """world == 1 degenerate: canonical slots = just our own payload. No DMA,
-    so this body is interpret-mode safe — the ``-m pallas`` oracle tier runs
-    it on CPU to pin the slot-store layout against ``dma_ring_slots_ref``."""
-    del widx_ref  # the only worker is origin 0
-    slot_words_ref[...] = words_ref[...][None]
-    slot_scales_ref[...] = scales_ref[...]
+    """True when the remote-DMA kernel can run here (a TPU backend)."""
+    return jax.default_backend() == "tpu"
 
 
 def _ring_slots_kernel(
@@ -85,66 +63,61 @@ def _ring_slots_kernel(
     scales_ref,
     slot_words_ref,
     slot_scales_ref,
-    comm_words,
-    comm_scales,
+    local_sems,
     send_sems,
     recv_sems,
     *,
+    axis: str,
     world: int,
 ):
-    """W−1 double-buffered remote-DMA hops → canonical origin-id slots.
+    """Own payload → slot ``my_id``, then W−1 remote-DMA hops → every slot.
 
-    ``widx_ref`` (SMEM) is this device's linear index on the ring axis;
-    ``comm_*`` are the 2-deep VMEM communication slots the RDMA alternates
-    between (send from ``step % 2``, receive into ``(step+1) % 2`` — the
-    arrival of hop *t* is the send buffer of hop *t+1*, so nothing is copied
-    between hops). Payloads stay sign-compressed on the wire for every hop.
+    Every operand stays in HBM. Hop *t* forwards slot ``(my_id − t) mod W``
+    (our own payload at t = 0, the previous hop's arrival after that) into
+    the same slot of the right neighbor, so each arrival lands directly in
+    its canonical origin-id slot and nothing is staged in VMEM. Payloads
+    stay sign-compressed on the wire for every hop.
     """
     my_id = widx_ref[0]
-    right = lax.rem(my_id + 1, world)
-    left = lax.rem(my_id + world - 1, world)
+    own = (
+        pltpu.make_async_copy(words_ref, slot_words_ref.at[my_id], local_sems.at[0]),
+        pltpu.make_async_copy(scales_ref, slot_scales_ref.at[my_id], local_sems.at[1]),
+    )
+    for c in own:
+        c.start()
+    for c in own:
+        c.wait()
+    if world == 1:
+        return
+    right = {axis: lax.rem(my_id + 1, world)}
+    left = {axis: lax.rem(my_id + world - 1, world)}
+    mesh_id = pltpu.DeviceIdType.MESH
 
     # neighbor barrier: no RDMA may land in a peer that has not yet entered
-    # the kernel (its comm slots would be uninitialized VMEM)
+    # the kernel (its slot buffers may still belong to an earlier op)
     barrier = pltpu.get_barrier_semaphore()
-    pltpu.semaphore_signal(barrier, inc=1, device_id=left)
-    pltpu.semaphore_signal(barrier, inc=1, device_id=right)
+    pltpu.semaphore_signal(barrier, inc=1, device_id=left, device_id_type=mesh_id)
+    pltpu.semaphore_signal(barrier, inc=1, device_id=right, device_id_type=mesh_id)
     pltpu.semaphore_wait(barrier, 2)
 
-    # canonical slot my_id ← own payload; comm slot 0 seeds hop 0's send
-    slot_words_ref[pl.ds(my_id, 1)] = words_ref[...][None]
-    slot_scales_ref[pl.ds(my_id, 1)] = scales_ref[...]
-    comm_words[0] = words_ref[...]
-    comm_scales[0] = scales_ref[...]
-
-    for step in range(world - 1):  # static W: unrolled, slots alternate
-        send_slot = step % 2
-        recv_slot = (step + 1) % 2
-        w_rdma = pltpu.make_async_remote_copy(
-            src_ref=comm_words.at[send_slot],
-            dst_ref=comm_words.at[recv_slot],
-            send_sem=send_sems.at[0, send_slot],
-            recv_sem=recv_sems.at[0, recv_slot],
-            device_id=(right,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
-        )
-        s_rdma = pltpu.make_async_remote_copy(
-            src_ref=comm_scales.at[send_slot],
-            dst_ref=comm_scales.at[recv_slot],
-            send_sem=send_sems.at[1, send_slot],
-            recv_sem=recv_sems.at[1, recv_slot],
-            device_id=(right,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
-        )
-        w_rdma.start()
-        s_rdma.start()
-        w_rdma.wait()
-        s_rdma.wait()
-        # hop t's arrival originated at (my_id − t − 1) mod W; storing it by
-        # origin id reproduces the all-gather layout on every worker
-        origin = lax.rem(my_id + world - step - 1, world)
-        slot_words_ref[pl.ds(origin, 1)] = comm_words[recv_slot][None]
-        slot_scales_ref[pl.ds(origin, 1)] = comm_scales[recv_slot]
+    for step in range(world - 1):  # static W: unrolled
+        origin = lax.rem(my_id + world - step, world)
+        hops = [
+            pltpu.make_async_remote_copy(
+                src_ref=slots.at[origin],
+                dst_ref=slots.at[origin],
+                send_sem=send_sems.at[k, step],
+                recv_sem=recv_sems.at[k, step],
+                device_id=right,
+                device_id_type=mesh_id,
+            )
+            for k, slots in enumerate((slot_words_ref, slot_scales_ref))
+        ]
+        for h in hops:
+            h.start()
+        # the arrival of hop t is what hop t+1 forwards
+        for h in hops:
+            h.wait()
 
 
 def dma_ring_gather_slots(
@@ -153,58 +126,49 @@ def dma_ring_gather_slots(
     scales: jax.Array,
     *,
     world: int,
+    axis: str | None = None,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Gather all W compressed payloads into canonical origin-id slots.
 
-    ``widx`` () int32 ring index, ``words`` (nb, bs/32) u32, ``scales`` (nb,)
-    f32 → ``((W, nb, bs/32) u32, (W, nb) f32)``. Runs inside the fully-manual
-    ``shard_map`` of the bucketed aggregator. ``world == 1`` needs no DMA and
-    is interpret-safe; the multi-device kernel requires a real TPU ring.
+    ``widx`` () int32 index on the ring ``axis`` (needed when W > 1),
+    ``words`` (nb, bs/32) u32, ``scales`` (nb,) f32 →
+    ``((W, nb, bs/32) u32, (W, nb) f32)``. Runs inside
+    the fully-manual ``shard_map`` of the bucketed aggregator. ``world == 1``
+    needs no DMA and is interpret-safe; the multi-device kernel requires a
+    real TPU ring.
     """
-    if pltpu is None:
-        raise NotImplementedError("pallas TPU primitives unavailable in this jax build")
+    if world > 1 and axis is None:
+        raise ValueError("the remote-DMA ring needs its mesh axis when world > 1")
     nb, m = words.shape
-    widx = jnp.asarray(widx, jnp.int32).reshape(1)
-    scales_row = scales.astype(jnp.float32).reshape(1, nb)
-    out_shape = [
-        jax.ShapeDtypeStruct((world, nb, m), jnp.uint32),
-        jax.ShapeDtypeStruct((world, nb), jnp.float32),
-    ]
-    smem = getattr(pltpu, "SMEM", None) or pltpu.TPUMemorySpace.SMEM
-    in_specs = [
-        pl.BlockSpec(memory_space=smem),
-        pl.BlockSpec((nb, m), lambda: (0, 0)),
-        pl.BlockSpec((1, nb), lambda: (0, 0)),
-    ]
-    out_specs = [
-        pl.BlockSpec((world, nb, m), lambda: (0, 0, 0)),
-        pl.BlockSpec((world, nb), lambda: (0, 0)),
-    ]
-    if world == 1:
-        slot_w, slot_s = pl.pallas_call(
-            _seed_slots_kernel,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(widx, words, scales_row)
-        return slot_w, slot_s
+    # a slot is sliced out of its HBM stack, which Mosaic allows only for
+    # whole (8, 128) tiles: pad the bucket axes, drop the padding after
+    rows, cols = -(-nb // 8) * 8, -(-nb // 128) * 128
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     slot_w, slot_s = pl.pallas_call(
-        functools.partial(_ring_slots_kernel, world=world),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((2, nb, m), jnp.uint32),  # comm_words send/recv slots
-            pltpu.VMEM((2, 1, nb), jnp.float32),  # comm_scales send/recv slots
-            pltpu.SemaphoreType.DMA((2, 2)),  # send sems (words/scales × slot)
-            pltpu.SemaphoreType.DMA((2, 2)),  # recv sems
+        functools.partial(_ring_slots_kernel, axis=axis, world=world),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), hbm, hbm],
+        out_specs=[hbm, hbm],
+        out_shape=[
+            jax.ShapeDtypeStruct((world, rows, m), jnp.uint32),
+            jax.ShapeDtypeStruct((world, 1, cols), jnp.float32),
         ],
-        compiler_params=_compiler_params(RING_COLLECTIVE_ID),
+        scratch_shapes=[
+            pltpu.SemaphoreType.DMA((2,)),  # own-slot copies (words, scales)
+            pltpu.SemaphoreType.DMA((2, max(world - 1, 1))),  # send, per hop
+            pltpu.SemaphoreType.DMA((2, max(world - 1, 1))),  # recv, per hop
+        ],
+        compiler_params=pltpu.CompilerParams(
+            has_side_effects=True,
+            collective_id=RING_COLLECTIVE_ID if world > 1 else None,
+        ),
         interpret=interpret,
-    )(widx, words, scales_row)
-    return slot_w, slot_s
+    )(
+        jnp.asarray(widx, jnp.int32).reshape(1),
+        jnp.pad(words, ((0, rows - nb), (0, 0))),
+        jnp.pad(scales.astype(jnp.float32), (0, cols - nb)).reshape(1, cols),
+    )
+    return slot_w[:, :nb], slot_s[:, 0, :nb]
 
 
 def dma_ring_slot_stack(
@@ -226,7 +190,9 @@ def dma_ring_slot_stack(
     """
     axis = ef_axes[0]  # single-axis ring, validated at spec time
     widx = lax.axis_index(axis)
-    return dma_ring_gather_slots(widx, words, scales, world=world, interpret=interpret)
+    return dma_ring_gather_slots(
+        widx, words, scales, axis=axis, world=world, interpret=interpret
+    )
 
 
 def dma_ring_decode_mean(
@@ -248,6 +214,8 @@ def dma_ring_decode_mean(
 
     axis = ef_axes[0]  # single-axis ring, validated at spec time
     widx = lax.axis_index(axis)
-    slot_w, slot_s = dma_ring_gather_slots(widx, words, scales, world=world, interpret=interpret)
+    slot_w, slot_s = dma_ring_gather_slots(
+        widx, words, scales, axis=axis, world=world, interpret=interpret
+    )
     force = "pallas" if interpret and jax.default_backend() != "tpu" else None
     return ops.bucket_decompress_mean(slot_w, slot_s, force=force)

@@ -25,7 +25,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 1024
 WORDS_PER_ROW = LANE // 32
@@ -113,111 +115,139 @@ def ef_sign_compress(
 
 
 # ---------------------------------------------------------------------------
-# whole-bucket variants (repro.comm): one grid step per BUCKET, per-bucket
-# scale. A bucket is a (bucket_size,) slice of the flattened grad stream;
-# bucket_size % LANE == 0 keeps the pack's reduction axis in-register and the
-# word row a whole number of 128-lane tiles. γ is folded into the update by
-# the optimizer chain before bucketing, so p = g + e here.
+# whole-bucket variants (repro.comm): per-bucket scale over a (nb, bs) stack.
+# A bucket is a (bucket_size,) slice of the flattened grad stream; γ is folded
+# into the update by the optimizer chain before bucketing, so p = g + e here.
+#
+# Tiling: a grid step holds BUCKET_ROWS buckets × BUCKET_COLS elements, so
+# both block dims are whole (8, 128) f32 tiles (or the full bucket axis when
+# nb < BUCKET_ROWS); the last row-block may be partial. BUCKET_COLS elements
+# pack into exactly one 128-lane row of words, which is why bucket_size must
+# be a multiple of BUCKET_COLS. Per-bucket scalars travel as (nb, 1) columns.
+#
+# The bit-pack runs on the MXU: word c of a block row is Σ_j bit(32c+j)·2^j,
+# split into two 16-bit halves so every product and partial sum is an exact
+# bf16×bf16→f32 integer (< 2^16). The unpack shifts each word against a
+# 32-lane iota. Mosaic has no uint32 arithmetic, so words are int32 inside the
+# kernels and bitcast at the refs; the bit pattern is ref.py's.
 # ---------------------------------------------------------------------------
 
+BUCKET_ROWS = 32
+BUCKET_COLS = 4096
+PACK_WORDS = BUCKET_COLS // 32  # 128: one lane row of words per block row
 
-def _bucket_l1_kernel(g_ref, e_ref, out_ref):
-    p = g_ref[...].astype(jnp.float32) + e_ref[...].astype(jnp.float32)
-    out_ref[...] = jnp.sum(jnp.abs(p), axis=-1)
+
+def _bucket_tiling(nb: int, bs: int) -> tuple[int, tuple[int, int]]:
+    """→ (rows per block, grid) for a (nb, bs) bucket stack."""
+    if bs % BUCKET_COLS:
+        raise ValueError(f"bucket kernels need bucket_size % {BUCKET_COLS} == 0, got {bs}")
+    rows = min(nb, BUCKET_ROWS)
+    return rows, (pl.cdiv(nb, rows), bs // BUCKET_COLS)
 
 
-def bucket_l1(g, e, *, interpret: bool = False):
-    """Per-bucket L1 of p = g + e: (nb, bs) → (nb,)."""
-    nb, bs = g.shape
-    return pl.pallas_call(
-        _bucket_l1_kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, bs), lambda i: (i, 0)),
-            pl.BlockSpec((1, bs), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((nb,), jnp.float32),
-        interpret=interpret,
-    )(g, e)
+def _params(*semantics: str, interpret):
+    return None if interpret else pltpu.CompilerParams(dimension_semantics=semantics)
+
+
+def pack_matrix() -> jax.Array:
+    """(BUCKET_COLS, 2·PACK_WORDS) bf16 bit-pack weights: column c < 128 sums
+    bits 0-15 of word c, column 128 + c bits 16-31, each weighted 2^(bit % 16)."""
+    x = jnp.arange(BUCKET_COLS)[:, None]
+    c = jnp.arange(2 * PACK_WORDS)[None, :]
+    bit = x % 32
+    hit = (x // 32 == c % PACK_WORDS) & (bit // 16 == c // PACK_WORDS)
+    return jnp.where(hit, jnp.left_shift(1, bit % 16), 0).astype(jnp.bfloat16)
+
+
+def _pack_signs(nonneg, pack):
+    """(r, BUCKET_COLS) bool → (r, PACK_WORDS) uint32 words, bit j of word c = element 32c+j."""
+    halves = jnp.dot(nonneg.astype(jnp.bfloat16), pack, preferred_element_type=jnp.float32)
+    lo = halves[:, :PACK_WORDS].astype(jnp.int32)
+    hi = halves[:, PACK_WORDS:].astype(jnp.int32)
+    return pltpu.bitcast(lo | (hi << 16), jnp.uint32)
+
+
+def _unpack_signs(words):
+    """(r, PACK_WORDS) uint32 → (r, BUCKET_COLS) f32 of ±1."""
+    wd = pltpu.bitcast(words, jnp.int32)
+    r, m = wd.shape
+    shifts = lax.broadcasted_iota(jnp.int32, (r, m, 32), 2)
+    bits = (wd[:, :, None] >> shifts) & 1
+    return (2 * bits - 1).reshape(r, m * 32).astype(jnp.float32)
 
 
 def _bucket_stats_kernel(g_ref, e_ref, l1_ref, l2_ref):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        l1_ref[...] = jnp.zeros(l1_ref.shape, jnp.float32)
+        l2_ref[...] = jnp.zeros(l2_ref.shape, jnp.float32)
+
     p = g_ref[...].astype(jnp.float32) + e_ref[...].astype(jnp.float32)
-    l1_ref[...] = jnp.sum(jnp.abs(p), axis=-1)
-    l2_ref[...] = jnp.sum(p * p, axis=-1)
+    l1_ref[...] += jnp.sum(jnp.abs(p), axis=-1, keepdims=True)
+    l2_ref[...] += jnp.sum(p * p, axis=-1, keepdims=True)
 
 
 def bucket_stats(g, e, *, interpret: bool = False):
     """Per-bucket (L1, L2²) of p = g + e in one fused pass: (nb, bs) → 2×(nb,).
 
-    Supersedes :func:`bucket_l1` on the comm path: the same HBM read of
-    (g, e) also feeds the density metric, so the metric no longer costs a
-    second pass over the bucket stack.
+    The same HBM read of (g, e) feeds the scale and the density metric, so
+    the metric costs no second pass over the bucket stack.
     """
     nb, bs = g.shape
-    return pl.pallas_call(
+    rows, grid = _bucket_tiling(nb, bs)
+    block = pl.BlockSpec((rows, BUCKET_COLS), lambda i, j: (i, j))
+    col = pl.BlockSpec((rows, 1), lambda i, j: (i, 0))
+    l1, l2 = pl.pallas_call(
         _bucket_stats_kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, bs), lambda i: (i, 0)),
-            pl.BlockSpec((1, bs), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb,), jnp.float32),
-            jax.ShapeDtypeStruct((nb,), jnp.float32),
-        ],
+        grid=grid,
+        in_specs=[block, block],
+        out_specs=[col, col],
+        out_shape=[jax.ShapeDtypeStruct((nb, 1), jnp.float32)] * 2,
+        compiler_params=_params("parallel", "arbitrary", interpret=interpret),
         interpret=interpret,
     )(g, e)
+    return l1[:, 0], l2[:, 0]
 
 
-def _bucket_ef_sign_kernel(scale_ref, g_ref, e_ref, words_ref, e_new_ref):
-    scale = scale_ref[0]
+def bucket_l1(g, e, *, interpret: bool = False):
+    """Per-bucket L1 of p = g + e: (nb, bs) → (nb,)."""
+    return bucket_stats(g, e, interpret=interpret)[0]
+
+
+def _bucket_ef_sign_kernel(scale_ref, pack_ref, g_ref, e_ref, words_ref, e_new_ref):
     p = g_ref[...].astype(jnp.float32) + e_ref[...].astype(jnp.float32)
-    bits = (p >= 0).astype(jnp.uint32)  # (1, bs)
-    bs = bits.shape[-1]
-    b = bits.reshape(1, bs // 32, 32)
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    words_ref[...] = jnp.sum(b << shifts, axis=-1, dtype=jnp.uint32)
-    delta = scale * (2.0 * bits.astype(jnp.float32) - 1.0)
-    e_new_ref[...] = p - delta
+    nonneg = p >= 0
+    words_ref[...] = _pack_signs(nonneg, pack_ref[...])
+    e_new_ref[...] = p - scale_ref[...] * jnp.where(nonneg, 1.0, -1.0)
 
 
 def bucket_ef_sign_compress(g, e, scales, *, interpret: bool = False):
     """(nb, bs) p = g+e → ((nb, bs/32) u32 packed signs, (nb, bs) residual)."""
     nb, bs = g.shape
+    rows, grid = _bucket_tiling(nb, bs)
+    block = pl.BlockSpec((rows, BUCKET_COLS), lambda i, j: (i, j))
     return pl.pallas_call(
         _bucket_ef_sign_kernel,
-        grid=(nb,),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda i: (i,)),  # per-bucket scale
-            pl.BlockSpec((1, bs), lambda i: (i, 0)),
-            pl.BlockSpec((1, bs), lambda i: (i, 0)),
+            pl.BlockSpec((rows, 1), lambda i, j: (i, 0)),  # per-bucket scale
+            pl.BlockSpec((BUCKET_COLS, 2 * PACK_WORDS), lambda i, j: (0, 0)),
+            block,
+            block,
         ],
-        out_specs=[
-            pl.BlockSpec((1, bs // 32), lambda i: (i, 0)),
-            pl.BlockSpec((1, bs), lambda i: (i, 0)),
-        ],
+        out_specs=[pl.BlockSpec((rows, PACK_WORDS), lambda i, j: (i, j)), block],
         out_shape=[
             jax.ShapeDtypeStruct((nb, bs // 32), jnp.uint32),
             jax.ShapeDtypeStruct((nb, bs), jnp.float32),
         ],
+        compiler_params=_params("parallel", "parallel", interpret=interpret),
         interpret=interpret,
-    )(scales, g, e)
+    )(scales.astype(jnp.float32).reshape(nb, 1), pack_matrix(), g, e)
 
 
-def _bucket_accumulate_kernel(scales_ref, acc_ref, words_ref, out_ref):
-    # acc block (1, bs); words block (1, bs/32); scale (1,) — one VMEM-resident
-    # decode fused with the add, no ±scale tensor ever hits HBM
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    wd = words_ref[...]  # (1, bs/32)
-    bits = (wd[..., None] >> shifts) & jnp.uint32(1)
-    signs = 2.0 * bits.reshape(out_ref.shape).astype(jnp.float32) - 1.0
-    out_ref[...] = acc_ref[...] + scales_ref[0] * signs
+def _bucket_accumulate_kernel(scale_ref, acc_ref, words_ref, out_ref):
+    # one VMEM-resident decode fused with the add: no ±scale tensor hits HBM
+    out_ref[...] = acc_ref[...] + scale_ref[...] * _unpack_signs(words_ref[...])
 
 
 def bucket_sign_accumulate(acc, words, scales, *, interpret: bool = False):
@@ -230,46 +260,47 @@ def bucket_sign_accumulate(acc, words, scales, *, interpret: bool = False):
     cost is spread across the W−1 hops instead of piling up after the last.
     """
     nb, bs = acc.shape
+    rows, grid = _bucket_tiling(nb, bs)
+    block = pl.BlockSpec((rows, BUCKET_COLS), lambda i, j: (i, j))
     return pl.pallas_call(
         _bucket_accumulate_kernel,
-        grid=(nb,),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1, bs), lambda i: (i, 0)),
-            pl.BlockSpec((1, bs // 32), lambda i: (i, 0)),
+            pl.BlockSpec((rows, 1), lambda i, j: (i, 0)),
+            block,
+            pl.BlockSpec((rows, PACK_WORDS), lambda i, j: (i, j)),
         ],
-        out_specs=pl.BlockSpec((1, bs), lambda i: (i, 0)),
+        out_specs=block,
         out_shape=jax.ShapeDtypeStruct((nb, bs), jnp.float32),
+        compiler_params=_params("parallel", "parallel", interpret=interpret),
         interpret=interpret,
-    )(scales, acc, words)
+    )(scales.astype(jnp.float32).reshape(nb, 1), acc, words)
 
 
 def _bucket_decompress_mean_kernel(scales_ref, words_ref, out_ref, *, w: int):
-    # words block: (w, 1, bs/32); scales: (w, 1); out: (1, bs)
-    shifts = jnp.arange(32, dtype=jnp.uint32)
+    # words block (w, rows, PACK_WORDS); scales (w, rows, 1); out (rows, BUCKET_COLS)
     acc = jnp.zeros(out_ref.shape, jnp.float32)
-    for i in range(w):  # w is static; unrolled vector loop
-        wd = words_ref[i]  # (1, bs/32)
-        bits = (wd[..., None] >> shifts) & jnp.uint32(1)
-        signs = 2.0 * bits.reshape(out_ref.shape).astype(jnp.float32) - 1.0
-        acc = acc + scales_ref[i, 0] * signs
+    for i in range(w):  # w is static; the same order as the ref's loop
+        acc = acc + scales_ref[i] * _unpack_signs(words_ref[i])
     out_ref[...] = acc / w
 
 
 def bucket_sign_decompress_mean(words, scales, *, interpret: bool = False):
     """(W, nb, bs/32) u32 + (W, nb) scales → (nb, bs) mean of ±scaleᵢᵦ."""
     w, nb, m = words.shape
+    rows, grid = _bucket_tiling(nb, m * 32)
     return pl.pallas_call(
         functools.partial(_bucket_decompress_mean_kernel, w=w),
-        grid=(nb,),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((w, 1), lambda i: (0, i)),
-            pl.BlockSpec((w, 1, m), lambda i: (0, i, 0)),
+            pl.BlockSpec((w, rows, 1), lambda i, j: (0, i, 0)),
+            pl.BlockSpec((w, rows, PACK_WORDS), lambda i, j: (0, i, j)),
         ],
-        out_specs=pl.BlockSpec((1, m * 32), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((rows, BUCKET_COLS), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nb, m * 32), jnp.float32),
+        compiler_params=_params("parallel", "parallel", interpret=interpret),
         interpret=interpret,
-    )(scales, words)
+    )(scales.astype(jnp.float32).reshape(w, nb, 1), words)
 
 
 # ---------------------------------------------------------------------------

@@ -9,8 +9,9 @@ flat tensor:
     Δ      = scale·sign(γg+e)   (reconstructable from words+scale — not returned)
 
 Implementation selection: the Pallas path runs on TPU (or anywhere with
-``interpret=True``); the jnp reference path is the default on CPU so that the
-512-device dry-run never traces a Pallas call. ``force`` overrides for tests.
+``interpret=True``); the jnp reference path is the default elsewhere so that
+the 512-device dry-run never traces a Pallas call. ``force`` overrides for
+tests and for the on-chip comparison against the reference.
 """
 
 from __future__ import annotations
@@ -83,14 +84,7 @@ def decompress_mean(words: jax.Array, scales: jax.Array, *, force: str | None = 
     return ref.sign_decompress_mean_ref(words, scales)
 
 
-BUCKET_PALLAS_MULTIPLE = 4096  # bs/32 words must tile the 128-lane registers
-
-
-def _bucket_use_pallas(force: str | None, bs: int) -> tuple[bool, bool]:
-    use_pallas, interpret = _use_pallas(force)
-    if bs % BUCKET_PALLAS_MULTIPLE != 0 and force != "pallas":
-        return False, False
-    return use_pallas, interpret
+BUCKET_PALLAS_MULTIPLE = ef_sign.BUCKET_COLS  # one 128-lane row of words per block
 
 
 @functools.partial(jax.jit, static_argnames=("fixed_scale", "force"))
@@ -117,7 +111,7 @@ def ef_sign_bucket_step(
     nb, bs = g.shape
     if bs % 32 != 0:
         raise ValueError(f"bucket_size must be a multiple of 32, got {bs}")
-    use_pallas, interpret = _bucket_use_pallas(force, bs)
+    use_pallas, interpret = _use_pallas(force)
     if use_pallas:
         l1, l2sq = ef_sign.bucket_stats(g, e, interpret=interpret)
     else:
@@ -137,7 +131,7 @@ def ef_sign_bucket_step(
 @functools.partial(jax.jit, static_argnames=("force",))
 def bucket_decompress_mean(words: jax.Array, scales: jax.Array, *, force: str | None = None):
     """Mean of W bucket payload stacks: (W, nb, bs/32) + (W, nb) → (nb, bs)."""
-    use_pallas, interpret = _bucket_use_pallas(force, words.shape[-1] * 32)
+    use_pallas, interpret = _use_pallas(force)
     if use_pallas:
         return ef_sign.bucket_sign_decompress_mean(words, scales, interpret=interpret)
     return ref.bucket_decompress_mean_ref(words, scales)
@@ -151,7 +145,7 @@ def bucket_sign_accumulate(
 
     (nb, bs) f32 + (nb, bs/32) u32 + (nb,) f32 → (nb, bs) f32.
     """
-    use_pallas, interpret = _bucket_use_pallas(force, words.shape[-1] * 32)
+    use_pallas, interpret = _use_pallas(force)
     if use_pallas:
         return ef_sign.bucket_sign_accumulate(acc, words, scales, interpret=interpret)
     return ref.bucket_sign_accumulate_ref(acc, words, scales)

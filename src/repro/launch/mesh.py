@@ -15,27 +15,17 @@ Topology (TPU v5e target):
 from __future__ import annotations
 
 import jax
-
-try:  # jax ≥ 0.5: meshes carry explicit per-axis sharding modes
-    from jax.sharding import AxisType
-except ImportError:  # jax ≤ 0.4.x: every axis is implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def use_mesh(mesh):
-    """Version-compat mesh context: ``jax.set_mesh`` where it exists (jax ≥
-    0.6), else the 0.4.x ``Mesh`` resource-env context manager — both make
-    ``mesh`` the ambient mesh for jit/shard_map inside the ``with`` block."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
+    """``jax.set_mesh``: ``mesh`` is the ambient mesh for jit/shard_map inside
+    the ``with`` block."""
+    return jax.set_mesh(mesh)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

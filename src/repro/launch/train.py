@@ -4,8 +4,8 @@
         --steps 200 --batch 8 --seq 128 --optimizer ef_signsgd \
         --strategy dense --reduced
 
-On this CPU container use ``--reduced`` (the smoke variant); on a real
-cluster drop it and point ``--mesh-data/--mesh-model`` at the slice. The
+On the CPU use ``--reduced`` (the smoke variant); on TPU chips drop it and
+point ``--mesh-data/--mesh-model`` at the slice. The
 ``--strategy`` flag selects the gradient exchange (dense | ef_allgather |
 ef_ring | ef_alltoall | majority_vote | ef_coord_median | ef_trimmed_mean |
 ef_norm_filter); ``--overlap`` pipelines the compressed exchange with
@@ -29,6 +29,7 @@ from repro.launch.mesh import make_host_mesh
 from repro.obs import sink as obs_sink
 from repro.obs.telemetry import TELEMETRY_CHOICES
 from repro.train.loop import TrainJob, run_training
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -58,7 +59,7 @@ def main():
         "--backend", default="auto", choices=list(BACKEND_CHOICES),
         help="collective backend for the payload-mean exchange (repro.comm."
         "backends: xla | ring | pallas_dma; auto resolves per mesh — "
-        "pallas_dma falls back to ring off-TPU with a logged reason)",
+        "pallas_dma runs as ring on the CPU, with a logged reason)",
     )
     ap.add_argument(
         "--overlap", action="store_true",
@@ -133,6 +134,7 @@ def main():
     )
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

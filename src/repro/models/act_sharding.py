@@ -23,14 +23,8 @@ _state = threading.local()
 
 
 def _current_mesh():
-    """The ambient mesh: ``jax.sharding.get_abstract_mesh`` on jax ≥ 0.5,
-    else the 0.4.x resource-env physical mesh (set by ``use_mesh``)."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    from jax._src import mesh as mesh_lib
-
-    return mesh_lib.thread_resources.env.physical_mesh
+    """The ambient mesh (set by ``use_mesh``)."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def _ctx():

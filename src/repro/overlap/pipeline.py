@@ -124,7 +124,6 @@ def build_overlapped_aggregator(
     w = world_size(mesh, ef_axes)
     bs = layout.bucket_size
     ef = ef_axes if len(ef_axes) != 1 else ef_axes[0]
-    masks = tuple(bucketize.valid_mask(layout, gi) for gi in range(len(layout.groups)))
     bucket_bits = comp.wire_bits(bs)
     has_err = strategy != "majority_vote"
     # byz_f == 0 robust collapses to the mean reading (bitwise ef_allgather)
@@ -134,6 +133,8 @@ def build_overlapped_aggregator(
     def body(buckets, err, srv, key):
         del srv
         widx = _worker_index(ef_axes)
+        # traced, so the padding masks never become full-size constants
+        masks = [bucketize.valid_mask(layout, gi) for gi in range(n_dtype)]
         keys_full = [None] * n_dtype
         if not comp.deterministic:
             for gi in range(n_dtype):

@@ -110,13 +110,26 @@ def _local_chain(job: TrainJob) -> optim.Transform:
     raise ValueError(job.optimizer)
 
 
-def run_training(job: TrainJob, batches: Iterator[dict] | None = None, log_fn: Callable | None = None):
+@dataclasses.dataclass
+class PreparedRun:
+    """A job's initial state and step, built but not yet run (see
+    :func:`prepare_training`)."""
+
+    state: Any
+    bundle: steps_lib.StepBundle
+    step_fn: Callable
+    example: dict
+    batches: Iterator[dict]
+    spec: CommSpec
+    policy: str
+    ef_axes: tuple[str, ...]
+
+
+def prepare_training(job: TrainJob, batches: Iterator[dict] | None = None) -> PreparedRun:
+    """Initialise the state and build the jitted train step of a
+    data-parallel ``job``. Call under ``use_mesh(job.mesh)``."""
     cfg, mesh = job.cfg, job.mesh
     spec = job.comm_spec()
-    if spec.fed is not None:
-        from repro.fed import loop as fed_loop  # lazy: keeps fed out of DP runs
-
-        return fed_loop.run_fed_training(job, spec, log_fn=log_fn)
     policy = job.policy or default_policy(cfg)
     rules = ShardingRules(cfg, mesh, policy)
     ef_axes = ef_axis_names(mesh, policy) if spec.strategy != "dense" else ()
@@ -127,18 +140,32 @@ def run_training(job: TrainJob, batches: Iterator[dict] | None = None, log_fn: C
         batches = synthetic.token_batches(job.seed, job.batch, job.seq, cfg.vocab_size)
 
     bucket_size = spec.bucket_size if spec.strategy != "dense" else None
+    state = init_train_state(
+        cfg, key, chain, spec.strategy, mesh, ef_axes, bucket_size=bucket_size
+    )
+    example = next(batches)
+    bundle = steps_lib.make_train_step(
+        cfg, mesh, rules,
+        spec=spec, local_chain=chain, ef_axes=ef_axes,
+        batch_example=example, state_example=state, microbatches=job.microbatches,
+    )
+    state = jax.device_put(state, bundle.in_shardings[0])
+    return PreparedRun(state, bundle, bundle.jit(), example, batches, spec, policy, ef_axes)
+
+
+def run_training(
+    job: TrainJob, batches: Iterator[dict] | None = None, log_fn: Callable | None = None
+):
+    mesh = job.mesh
+    spec = job.comm_spec()
+    if spec.fed is not None:
+        from repro.fed import loop as fed_loop  # lazy: keeps fed out of DP runs
+
+        return fed_loop.run_fed_training(job, spec, log_fn=log_fn)
     with use_mesh(mesh):
-        state = init_train_state(
-            cfg, key, chain, spec.strategy, mesh, ef_axes, bucket_size=bucket_size
-        )
-        example = next(batches)
-        bundle = steps_lib.make_train_step(
-            cfg, mesh, rules,
-            spec=spec, local_chain=chain, ef_axes=ef_axes,
-            batch_example=example, state_example=state, microbatches=job.microbatches,
-        )
-        state = jax.device_put(state, bundle.in_shardings[0])
-        step_fn = bundle.jit()
+        prep = prepare_training(job, batches)
+        state, bundle, step_fn = prep.state, prep.bundle, prep.step_fn
+        example, batches, policy, ef_axes = prep.example, prep.batches, prep.policy, prep.ef_axes
 
         writer = None
         if job.log_dir:

@@ -15,15 +15,13 @@ Bucketed EF strategies (the default wire path, ``bucket_size`` set)
     untouched. Updates are flattened into fixed-size buckets
     (:mod:`repro.comm.bucketize`) and exchanged by the fully-manual
     collective in :mod:`repro.comm.collective` — the only ``shard_map`` in
-    the step, with every mesh axis manual, which is what keeps jaxlib
-    0.4.x's partial-manual ``IsManualSubgroup`` abort unreachable.
+    the step, with every mesh axis manual.
 
 Per-leaf EF strategies (``bucket_size=None`` fallback)
     The original ``shard_map``-around-the-model path: manual over the EF
     worker axes with every other mesh axis GSPMD-auto, compressing leaf by
     leaf (:mod:`repro.core.aggregation`). Preserves intra-leaf shardings (no
-    flatten), so it remains the choice for the giant-model dry-run — but the
-    partial-manual configuration aborts on jaxlib 0.4.x.
+    flatten), so it remains the choice for the giant-model dry-run.
 
 Worker-local state (EF residuals, momentum traces) is stacked on a leading
 EF-world axis and sharded over the EF axes; see ``state_specs``.

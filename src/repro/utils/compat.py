@@ -1,9 +1,4 @@
-"""jax version-compat shims (target range: 0.4.37 → current).
-
-The repo is written against the modern jax API surface; everything that
-drifted between 0.4.x and 0.6+ funnels through here so call sites stay
-clean. Companion shims live in repro.launch.mesh (``use_mesh``, AxisType).
-"""
+"""``shard_map`` with the repo's defaults."""
 
 from __future__ import annotations
 
@@ -12,31 +7,12 @@ from typing import Callable
 import jax
 
 
-def jax_version_tuple() -> tuple[int, int]:
-    return tuple(int(x) for x in jax.__version__.split(".")[:2])
-
-
-# jaxlib 0.4.x: known-broken partial-manual shard_map collectives etc.;
-# version-keyed test xfails hang off this single flag
-OLD_JAX = jax_version_tuple() < (0, 5)
-
-
 def shard_map(f: Callable, *, mesh, in_specs, out_specs, manual_axes=None):
-    """``jax.shard_map`` (≥ 0.6, ``axis_names``/``check_vma``) or
-    ``jax.experimental.shard_map`` (0.4.x, ``auto``/``check_rep``).
-
-    ``manual_axes``: mesh axes the body references collectively; the rest stay
-    GSPMD-auto. ``None`` means fully manual. Replication checking is disabled
-    in both dialects — the strategies' RNG-key plumbing defeats the inferencer.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {"check_vma": False}
-        if manual_axes is not None:
-            kw["axis_names"] = frozenset(manual_axes)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kw = {"check_rep": False}
-    if manual_axes is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    return _shard_map(f, mesh, in_specs, out_specs, **kw)
+    """``jax.shard_map`` with replication checking off (the strategies' RNG-key
+    plumbing defeats the inferencer). ``manual_axes``: mesh axes the body
+    references collectively; the rest stay GSPMD-auto. ``None`` means fully
+    manual."""
+    kw = {} if manual_axes is None else {"axis_names": frozenset(manual_axes)}
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False, **kw
+    )

@@ -1,8 +1,3 @@
-import os
-
-import pytest
-
-
 def pytest_configure(config):
     config.addinivalue_line("markers", "slow: multi-device subprocess tests")
     config.addinivalue_line(
@@ -18,36 +13,8 @@ def pytest_configure(config):
     )
     config.addinivalue_line(
         "markers",
-        "tpu: needs a real TPU backend (Pallas compile, not interpret mode); "
-        "auto-skipped on CPU/GPU so CI on GitHub-hosted runners stays green",
-    )
-    config.addinivalue_line(
-        "markers",
-        "pallas: exercises a Pallas kernel or its interpret-mode reference "
-        "oracle; runs everywhere (interpret mode works on CPU) and is selected "
-        "as its own CI step so kernel regressions are visible — parts that "
-        "additionally need hardware carry the tpu marker on top",
+        "pallas: exercises a Pallas kernel in interpret mode, its reference "
+        "oracle, or its compile for a described TPU; runs everywhere and is "
+        "selected as its own CI step so kernel regressions are visible",
     )
 
-
-def pytest_collection_modifyitems(config, items):
-    import jax
-
-    # REPRO_XFAIL_STRICT=1 (set on the latest-jax CI leg) upgrades EVERY
-    # xfail marker to strict, overriding per-marker strict=False opt-outs:
-    # a version-keyed marker that survives a jax upgrade and starts XPASSing
-    # turns the job red instead of passing silently — which is what makes the
-    # ROADMAP's "retire the markers when the pin moves" item enforceable.
-    force_strict = bool(os.environ.get("REPRO_XFAIL_STRICT"))
-
-    on_tpu = jax.default_backend() == "tpu"
-    skip_tpu = pytest.mark.skip(reason="requires TPU backend (Pallas compile path)")
-    for item in items:
-        if not on_tpu and "tpu" in item.keywords:
-            item.add_marker(skip_tpu)
-        if force_strict:
-            for mark in list(item.iter_markers("xfail")):
-                if mark.kwargs.get("strict") is False:
-                    kwargs = dict(mark.kwargs, strict=True)
-                    # prepended so it is evaluated before the lax original
-                    item.add_marker(pytest.mark.xfail(*mark.args, **kwargs), append=False)

@@ -93,6 +93,18 @@ def test_pallas_dma_falls_back_to_ring_off_tpu(caplog):
     assert "falling back" in caplog.text and "pallas_dma" in caplog.text
 
 
+def test_pallas_dma_unavailable_off_cpu_raises(monkeypatch):
+    """The ring substitution is a CPU-test convenience; anywhere else an
+    unavailable ``pallas_dma`` is an error, not a silent change of transport."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("pallas_dma is available on a TPU")
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    mesh = make_host_mesh(data=1, model=1)
+    spec = CommSpec(strategy="ef_allgather", bucket_size=128, backend="pallas_dma")
+    with pytest.raises(BackendCapabilityError, match="remote-DMA ring"):
+        backends.resolve(spec, mesh, ("data",))
+
+
 def test_ring_backend_requires_single_axis():
     mesh = make_host_mesh(data=1, model=1)
     spec = CommSpec(strategy="ef_ring", bucket_size=128)
@@ -234,6 +246,31 @@ def test_spec_tolerance_is_world_dependent():
     spec.validate(world=4)  # 2f < W: fine
     with pytest.raises(ToleranceError, match="robust"):
         CommSpec(strategy="ef_allgather", bucket_size=128, byz=ByzConfig(f=1)).validate(world=8)
+
+
+def test_spec_rejects_untileable_sign_bucket_size_on_tpu(monkeypatch):
+    """On a TPU the sign wire format always runs the Pallas bucket kernels, so
+    a bucket size they cannot tile fails validation instead of falling back."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(WireFormatError, match="bucket_size % 4096"):
+        CommSpec(strategy="ef_allgather", bucket_size=128).validate()
+    CommSpec(strategy="ef_allgather", bucket_size=8192).validate()
+    CommSpec(strategy="dense", bucket_size=128).validate()
+    CommSpec(strategy="ef_allgather", compressor="top_k", bucket_size=128).validate()
+
+
+def test_bucket_ops_never_fall_back_to_ref_on_tpu(monkeypatch):
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_backend", lambda: "tpu")
+    assert ops._use_pallas(None) == (True, False)
+    # a shape no other test traces, so the jit cache holds no CPU-path trace
+    words = jnp.zeros((3, 5, 4), jnp.uint32)
+    scales = jnp.ones((3, 5), jnp.float32)
+    with pytest.raises(ValueError, match="bucket_size % 4096"):
+        ops.bucket_decompress_mean(words, scales)
+    ref_mean = ops.bucket_decompress_mean(words, scales, force="ref")
+    assert ref_mean.shape == (5, 128)
 
 
 def test_spec_validate_chains_and_errors_are_value_errors():
@@ -490,39 +527,6 @@ def test_dma_ring_slot_stack_interpret_world_1():
     np.testing.assert_array_equal(np.asarray(out_s[0]), np.asarray(ref_s))
 
 
-@pytest.mark.pallas
-@pytest.mark.tpu
-def test_dma_ring_kernel_compiles_on_tpu():
-    """Hardware-only: the multi-device remote-DMA kernel itself (the interpret
-    path cannot model cross-chip RDMA). The trajectory contract below pins the
-    numerics via the ring fallback everywhere else."""
-    from repro.kernels import ops
-
-    world = jax.device_count()
-    if world < 2:
-        pytest.skip("needs a multi-chip TPU ring")
-    words, scales = _payload_stack(world, nb=4, bs=1024)
-    mesh = make_host_mesh(data=world, model=1)
-    from repro.utils.compat import shard_map
-
-    def body(w, s):
-        widx = jax.lax.axis_index("data")
-        slot_w, slot_s = dma_ring.dma_ring_gather_slots(widx, w[0], s[0], world=world)
-        return ops.bucket_decompress_mean(slot_w, slot_s)[None]
-
-    from jax.sharding import PartitionSpec as P
-
-    out = shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(P("data"), P("data")),
-        out_specs=P("data"),
-    )(words, scales)
-    want = ref.bucket_decompress_mean_ref(words, scales)
-    for widx in range(world):
-        np.testing.assert_array_equal(np.asarray(out[widx]), np.asarray(want))
-
-
 # ---------------------------------------------------------------------------
 # multi-worker subprocesses: spec-vs-legacy parity, pallas_dma trajectory
 # ---------------------------------------------------------------------------
@@ -702,6 +706,43 @@ print(json.dumps(res))
 """
 
 
+# the remote-DMA ring kernel itself, run by Pallas's TPU interpreter, which
+# simulates the DMA engines, semaphores and the barrier across CPU devices
+_DMA_INTERPRET_SCRIPT = r"""
+import json, os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(world)d"
+sys.path.insert(0, "%(repo)s/src")
+import jax, jax.numpy as jnp, numpy as np
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, PartitionSpec as P
+from repro.kernels import dma_ring, ref
+
+W, NB, M = %(world)d, 5, 128
+rng = np.random.default_rng(W)
+words = jnp.asarray(rng.integers(0, 2**32, size=(W, NB, M), dtype=np.uint32))
+scales = jnp.asarray(rng.random((W, NB), dtype=np.float32))
+mesh = Mesh(np.array(jax.devices()[:W]), ("data",))
+
+def body(w, s):
+    widx = jax.lax.axis_index("data")
+    sw, ss = dma_ring.dma_ring_gather_slots(
+        widx, w[0], s[0], axis="data", world=W, interpret=pltpu.InterpretParams()
+    )
+    return sw[None], ss[None]
+
+out_w, out_s = jax.jit(jax.shard_map(
+    body, mesh=mesh, in_specs=(P("data"), P("data")), out_specs=(P("data"), P("data")),
+    check_vma=False,
+))(words, scales)
+ok = []
+for widx in range(W):
+    rw, rs = ref.dma_ring_slots_ref(words, scales, widx)
+    ok.append(bool(np.array_equal(np.asarray(out_w[widx]), np.asarray(rw))
+                   and np.array_equal(np.asarray(out_s[widx]), np.asarray(rs))))
+print(json.dumps({"ok": ok}))
+"""
+
+
 def _run_driver(code_tmpl, **kw):
     code = code_tmpl % {"repo": REPO, **kw}
     proc = subprocess.run(
@@ -761,3 +802,13 @@ def test_robust_strategies_ride_every_backend(world, byz_f):
                 assert r[f"mean_collapse_{backend}"], (ctx, backend)
         else:
             assert r["overlap_matches_oneshot"], ctx
+
+
+@pytest.mark.slow
+@pytest.mark.pallas
+@pytest.mark.parametrize("world", [2, 4])
+def test_dma_ring_kernel_interpret_matches_slots_ref(world):
+    """Every worker's slots after the W−1 remote-DMA hops equal the all-gather
+    stack, as the hop-by-hop oracle says."""
+    out = _run_driver(_DMA_INTERPRET_SCRIPT, world=world)
+    assert out["ok"] == [True] * world

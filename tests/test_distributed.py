@@ -2,11 +2,8 @@
 single CPU device (the brief forbids a global device-count override).
 
 The EF strategies run through the bucketed comm layer (``repro.comm``) by
-default — per-worker grads via vmap + fully-manual shard_map collectives —
-which works on every supported jax, including jaxlib 0.4.x where the older
-per-leaf path's partial-manual shard_map aborts in XLA. The per-leaf
-``bucket_size=None`` fallback keeps its own (version-keyed xfail) coverage
-below so the latest-jax CI leg still exercises it.
+default — per-worker grads via vmap + fully-manual shard_map collectives.
+The per-leaf ``bucket_size=None`` fallback keeps its own coverage below.
 """
 
 import json
@@ -16,7 +13,6 @@ import sys
 
 import pytest
 
-from repro.utils import compat
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -114,32 +110,13 @@ def test_wire_bytes_ratio_signsgd_vs_dense():
 
 
 # ---------------------------------------------------------------------------
-# per-leaf fallback (bucket_size=None): jaxlib 0.4.x aborts
-# (`Check failed: sharding.IsManualSubgroup()`) on the partial-manual
-# shard_map this path needs — for the scan over layers and again for the
-# manual-axis collectives; fixed in newer XLA. The subprocess dies with
-# SIGABRT, so xfail (non-strict) keeps the documented-but-broken combo from
-# reddening CI on the pinned jax; the latest-jax CI leg runs it for real.
+# per-leaf fallback (bucket_size=None): partial-manual shard_map over the EF
+# axes, with the layer scan and the collectives inside it
 # ---------------------------------------------------------------------------
-
-_xfail_manual_subgroup = pytest.mark.xfail(
-    compat.OLD_JAX,
-    reason="XLA IsManualSubgroup abort in partial-manual shard_map on jaxlib "
-    "0.4.x (re-probed 2026-08-09 on the 0.4.37 pin: subprocess still dies "
-    "SIGABRT with `Check failed: sharding.IsManualSubgroup()` for both "
-    "ef_allgather and ef_alltoall — marker stays until the pin moves)",
-    strict=False,
-)
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize(
-    "strategy",
-    [
-        pytest.param("ef_allgather", marks=_xfail_manual_subgroup),
-        pytest.param("ef_alltoall", marks=_xfail_manual_subgroup),
-    ],
-)
+@pytest.mark.parametrize("strategy", ["ef_allgather", "ef_alltoall"])
 def test_train_step_per_leaf_fallback(strategy):
     out = _run(strategy, "tp", False, bucket=None)
     assert out["losses"][-1] < out["losses"][0], out["losses"]

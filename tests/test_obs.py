@@ -178,7 +178,7 @@ def test_aggregator_telemetry_full_invariants():
 
 def test_spans_survive_into_compiled_hlo():
     # named_scope is metadata-only: it must show up in the COMPILED program's
-    # op_name metadata (plain lowered text drops it on jax 0.4.x)
+    # op_name metadata, which the device-trace reduction keys on
     _, _, hlo = _run_w1_aggregator("off")
     assert obs_trace.SPAN_COMPRESS in hlo
     assert obs_trace.SPAN_DECODE in hlo

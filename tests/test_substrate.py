@@ -13,7 +13,6 @@ from repro.configs import ARCH_IDS, get_config, reduced
 from repro.core import optim
 from repro.data import synthetic
 from repro.launch.mesh import make_host_mesh, use_mesh
-from repro.utils import compat
 from repro.models import transformer as T
 from repro.sharding.rules import ShardingRules, default_policy
 from repro.train import checkpoint as ckpt
@@ -142,13 +141,6 @@ def test_signum_matches_paper_recursion():
 # ------------------------- 1-device training loop ------------------------ #
 
 
-@pytest.mark.xfail(
-    compat.OLD_JAX,
-    reason="25-step ef_signsgd loss decrease is marginal and misses under the "
-    "0.4.x RNG stream (re-probed 2026-08-09 on the 0.4.37 pin: loss 6.9823 vs "
-    "6.9276, still short — marker stays); converges on longer horizons",
-    strict=False,
-)
 def test_training_loop_reduces_loss_and_checkpoints():
     from repro.train.loop import TrainJob, run_training
 
