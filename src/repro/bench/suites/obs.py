@@ -1,16 +1,14 @@
 """Observability benches: telemetry overhead gate + run-record invariants.
 
 ``python -m repro.bench run --suite obs`` → BENCH_obs.json. The headline
-metric is the tentpole's acceptance gate: a W=4 subprocess compiles + times
-the same bucketed ``ef_allgather`` train step with ``telemetry="off"`` vs
-``"full"`` and telemetry must add ≤ 2% overhead. The gate compares the two
-compiled programs' trip-count-aware HLO costs (dot flops / HBM bytes via
-``repro.utils.hlo`` — deterministic and run-to-run stable); interleaved wall
-clock for both is recorded next to it with a noise-band tolerance, since
-shared CPU runners swing ±3% block to block, above the bound being gated. The deterministic rest pins the
-run-record contract: schema field count, in-graph wire bytes equal to the
-analytic model, density inside the unit interval, and the report CLI seeing
-no wire mismatch.
+metric is the acceptance gate: a W=4 subprocess compiles the same bucketed
+``ef_allgather`` train step with ``telemetry="off"`` vs ``"full"`` and
+telemetry must add ≤ 2% overhead. The gate compares the two compiled
+programs' trip-count-aware HLO costs (dot flops / HBM bytes via
+``repro.utils.hlo`` — deterministic and run-to-run stable); a CPU clock is
+no source of a time. The deterministic rest pins the run-record contract:
+schema field count, in-graph wire bytes equal to the analytic model, density
+inside the unit interval, and the report CLI seeing no wire mismatch.
 """
 
 from __future__ import annotations
@@ -23,15 +21,14 @@ import sys
 import jax
 
 from repro.bench.artifact import Metric
-from repro.bench.measure import wall_metric
 from repro.bench.registry import SkipBench, register_bench
 
 BUCKET_SIZE = 1 << 12
 WORLD = 4
-OVERHEAD_GATE = 1.02  # telemetry-on step wall ≤ 2% over telemetry-off
+OVERHEAD_GATE = 1.02  # telemetry-on compiled cost ≤ 2% over telemetry-off
 
 _DRIVER = r"""
-import os, json, time, statistics
+import os, json
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(world)d"
 import sys
 sys.path.insert(0, %(src)r)
@@ -47,7 +44,7 @@ from repro.comm import CommSpec, bucketize
 from repro.obs.telemetry import modeled_wire_bytes
 from repro.utils import hlo as hlo_util
 
-BUCKET, ITERS, WORLD = %(bucket)d, %(iters)d, %(world)d
+BUCKET, WORLD = %(bucket)d, %(world)d
 cfg = reduced(get_config("llama3_2_1b"))
 mesh = make_host_mesh(data=WORLD, model=1)
 rules = ShardingRules(cfg, mesh, "tp")
@@ -61,17 +58,11 @@ key = jax.random.PRNGKey(0)
 batch = {"tokens": jax.random.randint(key, (8, 256), 0, cfg.vocab_size),
          "labels": jax.random.randint(jax.random.PRNGKey(1), (8, 256), 0, cfg.vocab_size)}
 
-def one_call(fn, *a):
-    t0 = time.perf_counter()
-    jax.block_until_ready(fn(*a))
-    return (time.perf_counter() - t0) * 1e6
-
 out = {}
 with use_mesh(mesh):
     state0 = init_train_state(cfg, key, chain, "ef_allgather", mesh, ef_axes, bucket_size=BUCKET)
     layout = bucketize.build_layout(state0.params, BUCKET)
     out["modeled_wire_bytes"] = modeled_wire_bytes("ef_allgather", layout, WORLD, comp)
-    fns = {}
     for level in ("off", "full"):
         spec = CommSpec(strategy="ef_allgather", compressor=comp, bucket_size=BUCKET,
                         telemetry=level)
@@ -80,29 +71,16 @@ with use_mesh(mesh):
             state_example=state0)
         state = jax.device_put(state0, bundle.in_shardings[0])
         b = jax.device_put(batch, bundle.in_shardings[1])
-        # no donation: the timed loop reuses the same state buffers
+        # no donation: the full-telemetry step runs again below on this state
         fn = jax.jit(bundle.fn, in_shardings=bundle.in_shardings,
                      out_shardings=bundle.out_shardings)
         # trip-count-aware accounting (repro.utils.hlo): XLA's cost_analysis
         # counts the scan-over-layers body ONCE, underreporting the step ~12x
         # and inflating telemetry's relative share by the same factor
         parsed = hlo_util.analyze(fn.lower(state, b).compile().as_text())
-        fns[level] = (fn, state, b)
         out["cost_" + level] = {"flops": float(parsed["dot_flops"]),
                                 "bytes": float(parsed["hbm_bytes"])}
-    for fn, state, b in fns.values():
-        for _ in range(3):
-            jax.block_until_ready(fn(state, b))
-    # interleave the two programs round by round so slow machine drift
-    # (thermal, CI co-tenants) hits both sides equally — the gate is a
-    # 2%% ratio, far below the block-to-block wall variance on shared CPUs
-    xs = {"off": [], "full": []}
-    for _ in range(ITERS):
-        for level, (fn, state, b) in fns.items():
-            xs[level].append(one_call(fn, state, b))
-    for level, s in xs.items():
-        out[level] = {"median": statistics.median(s), "min": min(s)}
-    fn, state, b = fns["full"]
+    # fn, state and b are the full-telemetry step's, built last
     _, (_, metrics) = fn(state, b)
     t = metrics["obs"]
     out["telemetry"] = {
@@ -118,15 +96,12 @@ print(json.dumps(out))
 @register_bench("obs_telemetry_overhead", suites=("obs",))
 def obs_telemetry_overhead(ctx):
     """Telemetry-on vs -off bucketed EF step at W=4 (subprocess, 4 fake
-    devices): the ≤2%% compiled-cost overhead gate, interleaved wall times,
-    and the in-graph-vs-model invariants measured on the same steps."""
+    devices): the ≤2%% compiled-cost overhead gate and the in-graph-vs-model
+    invariants measured on the same step."""
     if jax.default_backend() != "cpu":
         raise SkipBench("subprocess driver assumes CPU fake devices")
     repo_src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "..", ".."))
-    code = _DRIVER % {
-        "src": repo_src, "bucket": BUCKET_SIZE, "world": WORLD,
-        "iters": 5 if ctx.fast else 15,
-    }
+    code = _DRIVER % {"src": repo_src, "bucket": BUCKET_SIZE, "world": WORLD}
     proc = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True, timeout=1200,
         env={**os.environ, "JAX_PLATFORMS": "cpu"},
@@ -136,11 +111,8 @@ def obs_telemetry_overhead(ctx):
     out = json.loads(proc.stdout.strip().splitlines()[-1])
 
     cfg_d = {"world": WORLD, "bucket_size": BUCKET_SIZE, "arch": "llama3_2_1b"}
-    wall_ratio = out["full"]["min"] / out["off"]["min"]
     # deterministic overhead: what telemetry ADDS to the compiled step, per
-    # the trip-count-aware HLO cost model — wall clock on shared CPU runners
-    # swings ±3% block to block, far above the 2% bound being gated, so the
-    # precise gate is the cost ratio and the wall ratio gets a noise band
+    # the trip-count-aware HLO cost model
     cost_ratio = max(
         out["cost_full"]["flops"] / max(out["cost_off"]["flops"], 1.0),
         out["cost_full"]["bytes"] / max(out["cost_off"]["bytes"], 1.0),
@@ -148,13 +120,6 @@ def obs_telemetry_overhead(ctx):
     tele = out["telemetry"]
     modeled = out["modeled_wire_bytes"]
     return [
-        wall_metric("obs_step_telemetry_off", {**_t(out["off"]), "iters": 0}, config=cfg_d),
-        wall_metric("obs_step_telemetry_full", {**_t(out["full"]), "iters": 0}, config=cfg_d),
-        Metric(
-            name="obs_telemetry_wall_ratio", value=round(wall_ratio, 4),
-            metric="ratio", unit="x", config=cfg_d,
-            direction="lower", tolerance=0.05, abs_tolerance=0.05,
-        ),
         Metric(
             name="obs_telemetry_cost_ratio", value=round(cost_ratio, 6),
             metric="ratio", unit="x", config=cfg_d,
@@ -246,6 +211,3 @@ def obs_record_contract(ctx):
         ),
     ]
 
-
-def _t(d: dict) -> dict:
-    return {"median_us": d["median"], "min_us": d["min"], "mean_us": d["median"]}

@@ -61,6 +61,7 @@ from repro.comm import bucketize, compressed, robust
 from repro.core.aggregation import AggInfo
 from repro.core.compressors import Compressor, ScaledSignCompressor
 from repro.obs import telemetry as obs_telemetry
+from repro.obs import trace as obs_trace
 from repro.utils import compat
 
 AxisNames = tuple[str, ...]
@@ -233,7 +234,8 @@ def build_bucketed_aggregator(
                     outs.append(robust.combine_view(strategy, view, byz_f))
                 else:
                     outs.append(view.mean())
-                new_errs.append(ne[None])
+                with obs_trace.span(obs_trace.SPAN_COMPRESS):  # the residual's worker axis
+                    new_errs.append(ne[None])
                 dens.append(jnp.mean(d_b))
                 err_norms.append(obs_telemetry.residual_l2(ne))
                 # every backend moves the same (w−1)·nb payloads per device
@@ -245,7 +247,8 @@ def build_bucketed_aggregator(
                 bp, ep = _pad_buckets(b, w * nbw), _pad_buckets(e, w * nbw)
                 mp = _pad_buckets(masks[gi], w * nbw)
                 payload, ne, d_b = compressed.ef_encode_buckets(comp, bp, ep, mask=mp)
-                new_errs.append(ne[:nb][None])
+                with obs_trace.span(obs_trace.SPAN_COMPRESS):
+                    new_errs.append(ne[:nb][None])
                 dens.append(jnp.mean(d_b[:nb]))
                 err_norms.append(obs_telemetry.residual_l2(ne[:nb]))
                 # route shard j of every worker's stream to worker j

@@ -140,16 +140,23 @@ def prepare_training(job: TrainJob, batches: Iterator[dict] | None = None) -> Pr
         batches = synthetic.token_batches(job.seed, job.batch, job.seq, cfg.vocab_size)
 
     bucket_size = spec.bucket_size if spec.strategy != "dense" else None
-    state = init_train_state(
-        cfg, key, chain, spec.strategy, mesh, ef_axes, bucket_size=bucket_size
-    )
+    # set-up spans are kept in every run (obs.trace.Recorder), with the
+    # compiles each one holds
+    obs_trace.RECORDER.listen()
+    with obs_trace.host_span(obs_trace.SPAN_SETUP_INIT, keep=True):
+        state = init_train_state(
+            cfg, key, chain, spec.strategy, mesh, ef_axes, bucket_size=bucket_size
+        )
+        jax.block_until_ready(state)
     example = next(batches)
-    bundle = steps_lib.make_train_step(
-        cfg, mesh, rules,
-        spec=spec, local_chain=chain, ef_axes=ef_axes,
-        batch_example=example, state_example=state, microbatches=job.microbatches,
-    )
-    state = jax.device_put(state, bundle.in_shardings[0])
+    with obs_trace.host_span(obs_trace.SPAN_SETUP_BUILD, keep=True):
+        bundle = steps_lib.make_train_step(
+            cfg, mesh, rules,
+            spec=spec, local_chain=chain, ef_axes=ef_axes,
+            batch_example=example, state_example=state, microbatches=job.microbatches,
+        )
+    with obs_trace.host_span(obs_trace.SPAN_SETUP_PLACE, keep=True):
+        state = jax.device_put(state, bundle.in_shardings[0])
     return PreparedRun(state, bundle, bundle.jit(), example, batches, spec, policy, ef_axes)
 
 

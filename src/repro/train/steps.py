@@ -296,9 +296,11 @@ def make_train_step(
         )
 
         def train_step(state: TrainState, batch):
-            (loss, metrics), grads = grad_fn(state.params, batch)
-            updates, opt_state = local_chain.update(grads, state.opt_state, state.params)
-            params = optim.apply_updates(state.params, updates)
+            with obs_trace.span(obs_trace.SPAN_BACKWARD):
+                (loss, metrics), grads = grad_fn(state.params, batch)
+            with obs_trace.span(obs_trace.SPAN_OPTIMIZER):
+                updates, opt_state = local_chain.update(grads, state.opt_state, state.params)
+                params = optim.apply_updates(state.params, updates)
             new_state = TrainState(params, opt_state, state.agg_state, state.step + 1)
             d = sum(x.size for x in jax.tree.leaves(grads))
             metrics = dict(metrics, wire_bytes=jnp.float32(8.0 * d), density=jnp.float32(1.0))
@@ -483,9 +485,10 @@ def _make_bucketed_ef_step(
             grads_w = comm_adversary.corrupt_worker_tree(
                 byz, grads_w, jax.random.fold_in(state.agg_state.key, 0x5A1), world=w
             )
-        updates_w, opt_state = jax.vmap(
-            lambda g, o: local_chain.update(g, o, state.params)
-        )(grads_w, state.opt_state)
+        with obs_trace.span(obs_trace.SPAN_OPTIMIZER):
+            updates_w, opt_state = jax.vmap(
+                lambda g, o: local_chain.update(g, o, state.params)
+            )(grads_w, state.opt_state)
         with obs_trace.span(obs_trace.SPAN_BUCKETIZE):
             buckets_w = jax.vmap(lambda u: comm_bucketize.flatten_buckets(layout, u))(
                 updates_w

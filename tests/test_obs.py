@@ -207,6 +207,84 @@ def test_wall_timers_accumulate_and_drain():
     assert timers.drain() == {}
 
 
+@pytest.fixture
+def recorder(monkeypatch):
+    """A fresh process recorder for one test (host_span appends to it)."""
+    rec = obs_trace.Recorder()
+    monkeypatch.setattr(obs_trace, "RECORDER", rec)
+    yield rec
+    rec.close()
+
+
+def test_recorder_records_nothing_while_off(recorder):
+    with obs_trace.host_span("step"):
+        with obs_trace.host_span("inner"):
+            pass
+    assert recorder.spans == [] and recorder._open == []
+
+
+def test_recorder_gives_nested_spans_their_parent(recorder):
+    recorder.start()
+    with obs_trace.host_span("outer"):
+        with obs_trace.host_span("inner"):
+            pass
+        with obs_trace.host_span("second"):
+            pass
+    recorder.stop()
+    with obs_trace.host_span("after"):
+        pass
+    with obs_trace.host_span(obs_trace.SPAN_SETUP_INIT, keep=True):
+        pass
+    names = [(s.name, s.parent) for s in recorder.spans]
+    assert names == [("obs.inner", "obs.outer"), ("obs.second", "obs.outer"), ("obs.outer", ""),
+                     (obs_trace.SPAN_SETUP_INIT, "")]
+    outer, inner = recorder.spans[2], recorder.spans[0]
+    assert outer.start_ns <= inner.start_ns <= inner.end_ns <= outer.end_ns
+    assert recorder.drain() and recorder.spans == []
+
+
+def test_recorder_counts_a_forced_recompile_once(recorder):
+    recorder.listen()
+    f = jax.jit(lambda x: jnp.tanh(x) * 3.0)
+    x, wider = jax.block_until_ready((jnp.ones((3, 5)), jnp.ones((4, 5))))
+    jax.block_until_ready(f(x))
+    before = recorder.counts()
+    jax.block_until_ready(f(x))  # cached: no compile
+    assert recorder.counts() == before
+    jax.block_until_ready(f(wider))  # a new input shape
+    after = recorder.counts()
+    assert after["compiles"] - before["compiles"] == 1
+    compiles = [s for s in recorder.spans if s.name == obs_trace.SPAN_COMPILE]
+    assert compiles[-1].fun_name.endswith("<lambda>)") and compiles[-1].end_ns >= compiles[-1].start_ns
+    # persistent-cache outcomes are counted where the cache is consulted
+    assert after["cache_hits"] + after["cache_misses"] >= before["cache_hits"] + before["cache_misses"]
+
+
+def test_prepare_training_emits_setup_spans_and_scopes_the_whole_step(recorder):
+    from repro.configs import get_config, reduced
+    from repro.train.loop import TrainJob, prepare_training
+
+    cfg = reduced(get_config("llama3_2_1b"))
+    mesh = make_host_mesh(data=1, model=1)
+    for strategy, scopes in (("dense", ("obs.backward", "obs.optimizer")),
+                             ("ef_allgather", ("obs.backward", "obs.optimizer", "obs.apply"))):
+        recorder.drain()
+        job = TrainJob(cfg=cfg, mesh=mesh, steps=2, batch=2, seq=16, optimizer="sgdm",
+                       strategy=strategy)
+        with use_mesh(mesh):
+            prep = prepare_training(job)
+            hlo = prep.step_fn.lower(prep.state, prep.example).compile().as_text()
+        setup = [s for s in recorder.spans if s.name.startswith("obs.setup.")]
+        assert [s.name for s in setup] == [obs_trace.SPAN_SETUP_INIT, obs_trace.SPAN_SETUP_BUILD,
+                                           obs_trace.SPAN_SETUP_PLACE]
+        assert all(s.parent == "" and s.end_ns >= s.start_ns for s in setup)
+        # eager init compiles (or loads) its ops inside obs.setup.init
+        assert any(s.name == obs_trace.SPAN_COMPILE and s.parent == obs_trace.SPAN_SETUP_INIT
+                   for s in recorder.spans)
+        for scope in scopes:
+            assert scope in hlo, (strategy, scope)
+
+
 # ---------------------------------------------------------------------------
 # JSONL sink
 # ---------------------------------------------------------------------------

@@ -35,6 +35,8 @@ def _layout(n_buckets_per_group, bucket_size):
     )
 
 
+# no deadline: the first example of each new shape compiles the reducer
+@hypothesis.settings(deadline=None)
 @hypothesis.given(ERR_ARRAYS)
 def test_residual_l2_finite_nonnegative_and_exact(err):
     got = float(obs_telemetry.residual_l2(jnp.asarray(err)))
