@@ -66,6 +66,11 @@ COUNTED_EVENTS = {
     "/jax/compilation_cache/cache_misses": "cache_misses",
 }
 COUNTERS = ("compiles", "cache_hits", "cache_misses")
+#: counts of the EF step as built, per dtype group (``<name>.<dtype>``), set
+#: by ``train.steps``: bucket rows moved straight from and to one leaf, and
+#: boundary rows assembled from pieces (``comm.bucketize.BucketGroup``)
+ROWS_ONE_LEAF = "bucketize.rows_one_leaf"
+ROWS_SHARED = "bucketize.rows_shared"
 
 
 class Span(NamedTuple):
@@ -135,6 +140,12 @@ class Recorder:
         with jax.profiler.TraceAnnotation(SPAN_CLOCK):
             self.anchor_ns = time.time_ns()
         return self.anchor_ns
+
+    def set_count(self, name: str, value: int) -> None:
+        """Set counter ``name``: a count of a program as built, not of
+        events, so building it again does not add to it."""
+        with self._lock:
+            self.counters[name] = value
 
     def counts(self) -> dict[str, int]:
         with self._lock:

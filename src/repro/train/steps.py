@@ -434,6 +434,9 @@ def _make_bucketed_ef_step(
     ef = ef_axes if len(ef_axes) != 1 else ef_axes[0]
     w = comm_collective.world_size(mesh, ef_axes)
     layout = comm_bucketize.build_layout(state_example.params, spec.bucket_size)
+    for group in layout.groups:
+        obs_trace.RECORDER.set_count(f"{obs_trace.ROWS_ONE_LEAF}.{group.dtype}", group.rows_one_leaf)
+        obs_trace.RECORDER.set_count(f"{obs_trace.ROWS_SHARED}.{group.dtype}", group.rows_shared)
     # a 1-worker world has no collective latency to hide — pipelining would
     # be pure dispatch overhead, so make_aggregator degenerates overlap to
     # the one-shot path there

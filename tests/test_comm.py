@@ -1,10 +1,17 @@
 """Bucketed comm layer: layout algebra, sign-packing edge cases (including
-the ``pack_signs_last``/``unpack_signs_last`` word-boundary cases), per-bucket
-EF compression, and the single-device collective path.
+the ``pack_signs_last``/``unpack_signs_last`` word-boundary cases), the
+leaf-to-row mapping of flatten/unflatten against a flat oracle, per-bucket EF
+compression, and the single-device collective path.
 
 These are deterministic (no hypothesis dependency) so the packing edge cases
 stay covered even where ``tests/test_compressors.py`` skips.
 """
+
+import inspect
+import json
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -119,6 +126,248 @@ def test_valid_mask_covers_padding_only():
     mask = np.asarray(bucketize.valid_mask(layout, 0))
     assert mask.sum() == layout.groups[0].valid
     assert mask.reshape(-1)[: layout.groups[0].valid].all()
+
+
+# ---------------------------------------------------------------------------
+# leaf-to-row mapping: flatten/unflatten move each leaf straight to and from
+# its rows, bit for bit what a flat concatenate/pad/slice of the group gives
+# ---------------------------------------------------------------------------
+
+
+def _flatten_oracle(layout, tree):
+    """The flat route: each group's leaves as one 1-d span, padded, viewed
+    as (n_buckets, bucket_size)."""
+    parts = [[] for _ in layout.groups]
+    for slot, leaf in zip(layout.slots, jax.tree.leaves(tree)):
+        parts[slot.group].append(leaf.reshape(-1).astype(jnp.float32))
+    out = []
+    for group, p in zip(layout.groups, parts):
+        flat = jnp.concatenate(p) if p else jnp.zeros((0,), jnp.float32)
+        flat = jnp.pad(flat, (0, group.n_buckets * layout.bucket_size - group.valid))
+        out.append(flat.reshape(group.n_buckets, layout.bucket_size))
+    return tuple(out)
+
+
+def _unflatten_oracle(layout, buckets):
+    flats = [b.reshape(-1) for b in buckets]
+    leaves = [
+        flats[s.group][s.offset : s.offset + s.size].reshape(s.shape).astype(s.dtype)
+        for s in layout.slots
+    ]
+    return jax.tree.unflatten(layout.treedef, leaves)
+
+
+_BF16 = jnp.bfloat16
+# name -> (bucket_size, [(shape, dtype)] in tree order)
+_ROW_CASES = {
+    # every leaf a whole number of buckets: no boundary row but padding
+    "bucket_aligned": (128, [((2, 8, 16), jnp.float32), ((4, 32), jnp.float32), ((3, 128), jnp.float32)]),
+    # like granite's embedding: starts 24 elements into a row, whole leaf rows
+    # on both ends (head 104 = 13 rows of 8, tail 24 = 3 rows)
+    "leaf_starts_mid_row": (128, [((3, 8), jnp.float32), ((40, 8), jnp.float32), ((8,), jnp.float32)]),
+    "small_leaves_share_a_row": (128, [((3,), jnp.float32), ((5, 2), jnp.float32), ((), jnp.float32),
+                                       ((7,), jnp.float32), ((2, 3, 4), jnp.float32)]),
+    "leaf_smaller_than_a_row": (128, [((5, 4), jnp.float32)]),
+    # deepseek's 11008 and 12800 at small scale: rows of 43 and 50 that no
+    # bucket boundary follows, mid-row starts
+    "minor_dim_not_dividing_the_bucket": (128, [((2, 7), jnp.float32), ((2, 16, 43), jnp.float32),
+                                               ((6, 50), jnp.float32), ((50, 6), jnp.float32)]),
+    # like granite's router: 32 lanes, alone in its rows, then mid-row
+    "lanes_of_32": (128, [((2, 8, 32), jnp.float32), ((3, 32), jnp.float32), ((4, 4, 32), jnp.float32)]),
+    "bf16_group_beside_f32_group": (64, [((7, 9), jnp.float32), ((3, 40), _BF16), ((130,), jnp.float32),
+                                         ((2, 64), _BF16), ((5,), _BF16)]),
+    "padding_in_the_last_row": (256, [((2, 256), jnp.float32), ((3, 70), jnp.float32)]),
+    "empty_leaves": (32, [((0,), jnp.float32), ((2, 0, 3), jnp.float32), ((40,), jnp.float32),
+                          ((0, 5), _BF16)]),
+}
+
+
+def _row_case(name, seed=0):
+    bs, specs = _ROW_CASES[name]
+    rng = np.random.default_rng(seed)
+    tree = {f"l{i:02d}": jnp.asarray(rng.normal(size=shape).astype(np.float32)).astype(dt)
+            for i, (shape, dt) in enumerate(specs)}
+    return tree, bucketize.build_layout(tree, bs)
+
+
+@pytest.mark.parametrize("case", sorted(_ROW_CASES))
+def test_leaf_rows_match_flat_route_bitwise(case):
+    tree, layout = _row_case(case)
+    bs = layout.bucket_size
+    flat = jax.jit(lambda t: bucketize.flatten_buckets(layout, t))(tree)
+    want = _flatten_oracle(layout, tree)
+    for got, exp in zip(flat, want):
+        assert got.dtype == jnp.float32 and got.shape == exp.shape
+        np.testing.assert_array_equal(np.asarray(got).view(np.uint32), np.asarray(exp).view(np.uint32))
+    # under vmap over a worker axis, as the EF step runs it
+    tree_w = jax.tree.map(lambda x: jnp.stack([x, -x]), tree)
+    flat_w = jax.jit(jax.vmap(lambda t: bucketize.flatten_buckets(layout, t)))(tree_w)
+    for w in range(2):
+        want_w = _flatten_oracle(layout, jax.tree.map(lambda x: x[w], tree_w))
+        for got, exp in zip(flat_w, want_w):
+            np.testing.assert_array_equal(np.asarray(got[w]).view(np.uint32), np.asarray(exp).view(np.uint32))
+    # the wire words of an EF-sign encode of them
+    for g, (got, exp) in enumerate(zip(flat, want)):
+        mask = bucketize.valid_mask(layout, g)
+        err = jnp.full(got.shape, 0.25, jnp.float32) * mask
+        p_got, e_got, _ = compressed.ef_encode_buckets(C.ScaledSignCompressor(), got, err, mask=mask)
+        p_exp, e_exp, _ = compressed.ef_encode_buckets(C.ScaledSignCompressor(), exp, err, mask=mask)
+        np.testing.assert_array_equal(np.asarray(p_got.data["words"]), np.asarray(p_exp.data["words"]))
+        np.testing.assert_array_equal(np.asarray(e_got), np.asarray(e_exp))
+    # unflatten of arbitrary rows (padding not zero), cast per leaf
+    rng = np.random.default_rng(1)
+    rows = tuple(jnp.asarray(rng.normal(size=(g.n_buckets, bs)).astype(np.float32)) for g in layout.groups)
+    back = jax.jit(lambda b: bucketize.unflatten_buckets(layout, b))(rows)
+    back_want = _unflatten_oracle(layout, rows)
+    for k in tree:
+        assert back[k].dtype == tree[k].dtype and back[k].shape == tree[k].shape
+        np.testing.assert_array_equal(
+            np.asarray(back[k].astype(jnp.float32)), np.asarray(back_want[k].astype(jnp.float32))
+        )
+    # round trip, and the row counts cover every row of every group
+    again = bucketize.unflatten_buckets(layout, flat)
+    for k in tree:
+        np.testing.assert_array_equal(np.asarray(again[k].astype(jnp.float32)),
+                                      np.asarray(tree[k].astype(jnp.float32)))
+    for g in layout.groups:
+        assert g.rows_one_leaf + g.rows_shared == g.n_buckets and g.rows_shared >= 0
+    assert layout.rows_one_leaf + layout.rows_shared == layout.n_buckets
+
+
+def test_leaf_runs_cover_each_leaf_once_in_row_order():
+    tree, layout = _row_case("minor_dim_not_dividing_the_bucket")
+    bs = layout.bucket_size
+    for slot in layout.slots:
+        assert sum(r.size for r in slot.runs) == slot.size
+        pos = slot.offset
+        for r in slot.runs:
+            assert (r.row, r.col) == divmod(pos, bs) and r.start == pos - slot.offset
+            if r.whole:
+                assert r.col == 0 and r.size % bs == 0
+            else:
+                assert r.col + r.size <= bs  # a partial run stays in its row
+            pos += r.size
+
+
+def _param_shapes(kind):
+    """The leaf shapes, in tree order, of the benchmark's two configurations."""
+    if kind == "granite_moe_1b_a400m-6l":
+        n, dt = 6, jnp.float32
+        shapes = [(n, 1024, 512), (n, 1024, 1024), (n, 1024, 1024), (n, 1024, 512), (n, 1024, 32),
+                  (n, 32, 1024, 512), (n, 32, 1024, 512), (n, 32, 512, 1024), (n, 1024), (n, 1024),
+                  (49408, 1024), (1024,)]
+    else:
+        n, dt = 2, jnp.bfloat16
+        shapes = [(n, 4096, 4096)] * 4 + [(n, 4096, 11008), (n, 4096, 11008), (n, 11008, 4096),
+                                          (n, 4096), (n, 4096), (12800, 4096), (4096,), (4096, 12800)]
+    return [jax.ShapeDtypeStruct(s, dt) for s in shapes]
+
+
+@pytest.mark.parametrize("kind,shared", [("granite_moe_1b_a400m-6l", 2), ("deepseek_7b-2l-v8", 3)])
+def test_benchmark_layouts_have_few_shared_rows(kind, shared):
+    layout = bucketize.build_layout(_param_shapes(kind), 65536)
+    (group,) = layout.groups
+    assert group.rows_shared == layout.rows_shared == shared
+    assert group.rows_one_leaf == group.n_buckets - shared
+
+
+def test_ef_step_records_row_counts_when_built(monkeypatch):
+    from repro.configs import get_config, reduced
+    from repro.core import optim
+    from repro.launch.mesh import ef_axis_names
+    from repro.obs import trace as obs_trace
+    from repro.sharding.rules import ShardingRules
+    from repro.train import steps as ST
+    from repro.train.state import init_train_state
+
+    rec = obs_trace.Recorder()
+    monkeypatch.setattr(obs_trace, "RECORDER", rec)
+    cfg = reduced(get_config("llama3_2_1b"))
+    mesh = make_host_mesh(data=1, model=1)
+    chain = optim.sgd(0.02)
+    with use_mesh(mesh):
+        ef_axes = ef_axis_names(mesh, "tp")
+        state = init_train_state(cfg, jax.random.PRNGKey(0), chain, "ef_allgather", mesh, ef_axes,
+                                 bucket_size=4096)
+        batch = {"tokens": jnp.zeros((2, 16), jnp.int32), "labels": jnp.zeros((2, 16), jnp.int32)}
+        ST.make_train_step(cfg, mesh, ShardingRules(cfg, mesh, "tp"),
+                           spec=CommSpec(strategy="ef_allgather", bucket_size=4096), local_chain=chain,
+                           ef_axes=ef_axes, batch_example=batch, state_example=state)
+    layout = bucketize.build_layout(state.params, 4096)
+    counts = rec.counts()
+    for g in layout.groups:
+        assert counts[f"{obs_trace.ROWS_ONE_LEAF}.{g.dtype}"] == g.rows_one_leaf
+        assert counts[f"{obs_trace.ROWS_SHARED}.{g.dtype}"] == g.rows_shared
+        assert g.rows_one_leaf + g.rows_shared == g.n_buckets and g.rows_shared > 0
+
+
+_STEP_DRIVER = r"""
+import os, json, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(world)d"
+sys.path.insert(0, os.path.join(%(repo)r, "src"))
+import jax, jax.numpy as jnp, numpy as np
+from repro.comm import CommSpec, bucketize
+from repro.configs import get_config, reduced
+from repro.core import optim
+from repro.launch.mesh import make_host_mesh, ef_axis_names, use_mesh
+from repro.sharding.rules import ShardingRules
+from repro.train.state import init_train_state
+from repro.train import steps as ST
+
+%(oracle)s
+
+W = %(world)d
+cfg = reduced(get_config("llama3_2_1b"))
+mesh = make_host_mesh(data=W, model=1)
+key = jax.random.PRNGKey(0)
+chain = optim.sgd(0.02, momentum=0.9)
+batch = {"tokens": jax.random.randint(key, (2 * W, 32), 0, cfg.vocab_size),
+         "labels": jax.random.randint(jax.random.PRNGKey(1), (2 * W, 32), 0, cfg.vocab_size)}
+
+def run():
+    with use_mesh(mesh):
+        rules = ShardingRules(cfg, mesh, "tp")
+        ef_axes = ef_axis_names(mesh, "tp")
+        state = init_train_state(cfg, key, chain, "ef_allgather", mesh, ef_axes, bucket_size=4096)
+        bundle = ST.make_train_step(cfg, mesh, rules, spec=CommSpec(strategy="ef_allgather", bucket_size=4096),
+                                    local_chain=chain, ef_axes=ef_axes, batch_example=batch,
+                                    state_example=state)
+        state = jax.device_put(state, bundle.in_shardings[0])
+        fn = bundle.jit()
+        losses = []
+        for _ in range(2):
+            state, (loss, _) = fn(state, jax.device_put(batch, bundle.in_shardings[1]))
+            losses.append(float(loss))
+        return losses, jax.device_get((state.params, state.agg_state.worker_error, state.opt_state))
+
+def same(a, b):
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    return len(la) == len(lb) and all(
+        x.dtype == y.dtype and np.array_equal(np.asarray(x).view(np.uint8), np.asarray(y).view(np.uint8))
+        for x, y in zip(la, lb))
+
+l_rows, (p_rows, e_rows, o_rows) = run()
+bucketize.flatten_buckets, bucketize.unflatten_buckets = _flatten_oracle, _unflatten_oracle
+l_flat, (p_flat, e_flat, o_flat) = run()
+print(json.dumps({"losses": l_rows == l_flat, "params": same(p_rows, p_flat),
+                  "residuals": same(e_rows, e_flat), "opt_state": same(o_rows, o_flat),
+                  "moved": not same(p_rows, init_train_state(cfg, key, chain, "ef_allgather", mesh,
+                      ef_axis_names(mesh, "tp"), bucket_size=4096).params)}))
+"""
+
+
+@pytest.mark.parametrize("world", [1, 2])
+def test_ef_step_matches_flat_route_bitwise(world):
+    """Two EF steps through ``_make_bucketed_ef_step``: parameters, residuals
+    and momentum bit for bit those of the flat concatenate/pad/slice route."""
+    oracle = inspect.getsource(_flatten_oracle) + "\n" + inspect.getsource(_unflatten_oracle)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = _STEP_DRIVER % {"repo": repo, "world": world, "oracle": oracle}
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True, timeout=600,
+                          env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out == {"losses": True, "params": True, "residuals": True, "opt_state": True, "moved": True}
 
 
 # ---------------------------------------------------------------------------
