@@ -5,7 +5,9 @@ A cell is an entry of ``workloads`` in ``BENCHMARK.json``. Its files:
 * ``chipbench/workloads/<cell>.json``: the comparison's limits, and the
   numbers not compared with the reason for each;
 * ``chipbench/configs/<config>.json``: the model, under the published
-  config's key names, with ``reduced``/``assumed``/``deployment``;
+  config's key names, with ``reduced``/``assumed``/``deployment``, and
+  ``family``, the module under ``chipbench/families/`` that knows its keys,
+  its reference and its counts (``decoder`` where the file names none);
 * ``chipbench/traffic/<traffic>.json``: the job (strategy, backend, bucket
   size, workers, rows per worker, sequence length, optimizer, step size).
 
@@ -18,28 +20,10 @@ import dataclasses
 import json
 from pathlib import Path
 
+import families
+
 HERE = Path(__file__).resolve().parent
 CHECKOUT = HERE.parent
-
-# published config key -> the program's ModelConfig field (and the reference's)
-MODEL_KEYS = {
-    "hidden_size": "d_model",
-    "intermediate_size": "d_ff",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim",
-    "num_hidden_layers": "num_layers",
-    "num_local_experts": "num_experts",
-    "num_experts_per_tok": "experts_per_token",
-    "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta",
-    "tie_word_embeddings": "tie_embeddings",
-    "param_dtype": "param_dtype",
-    "moe_capacity_factor": "capacity_factor",
-    "moe_aux_loss_coef": "aux_loss_coef",
-    "moe_z_loss_coef": "router_z_coef",
-}
-PROGRAM_ONLY_KEYS = {"compute_dtype": "compute_dtype"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -99,14 +83,15 @@ def load(name: str, bench: dict | None = None) -> Cell:
 
 def program_config(config: dict):
     """The program's ModelConfig of the named model with every key of the
-    file applied by ``dataclasses.replace``: the configuration as it is run."""
+    file that its family maps applied: the configuration as it is run."""
     from repro.configs import get_config
 
-    fields = {f: config[k] for k, f in {**MODEL_KEYS, **PROGRAM_ONLY_KEYS}.items() if k in config}
+    family = families.load(config)
+    fields = {f: config[k] for k, f in {**family.KEYS, **family.PROGRAM_ONLY_KEYS}.items() if k in config}
     return dataclasses.replace(get_config(config["program_config"]), **fields)
 
 
 def reference_model(config: dict):
-    from reference import Model
-
-    return Model(**{f: config[k] for k, f in MODEL_KEYS.items() if k in config})
+    """``(family, model)``: what ``reference.run_steps`` follows."""
+    family = families.load(config)
+    return family, family.model(config)

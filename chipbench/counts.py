@@ -1,34 +1,19 @@
 """Operations a training step requires and bytes the EF kernels must move.
 
 Counted from the configuration's shapes, never from the program, so that no
-program change can move the yardstick.
-
-Required FLOPs of one token's forward pass, per layer: the four attention
-projections, causal attention scores and values (on average half the
-context is visible), and either the SwiGLU MLP or the router plus the k
-chosen experts; then the LM head over the real vocabulary. Training is three
-forward passes' worth (forward, and twice that backward). Not counted:
-the embedding gather, norms and softmaxes, remat recompute, MoE one-hot
-dispatch/combine and capacity padding, and the padded vocabulary rows.
+program change can move the yardstick; per token and of parameters by the
+configuration's family (``families/<family>.py``). Training is three
+forward passes' worth (forward, and twice that backward).
 """
 
 from __future__ import annotations
 
+import families
+
 
 def forward_flops_per_token(m: dict, seq: int) -> float:
     """``m`` holds the configuration file's keys (published names)."""
-    d = m["hidden_size"]
-    hq, hkv, hd = m["num_attention_heads"], m["num_key_value_heads"], m["head_dim"]
-    f = m["intermediate_size"]
-    proj = 2 * d * (2 * hq * hd + 2 * hkv * hd)
-    scores = 2 * 2 * hq * hd * (seq / 2)
-    if m.get("num_local_experts"):
-        e, k = m["num_local_experts"], m["num_experts_per_tok"]
-        ffn = 2 * d * e + k * 3 * 2 * d * f
-    else:
-        ffn = 3 * 2 * d * f
-    head = 2 * d * m["vocab_size"]
-    return m["num_hidden_layers"] * (proj + scores + ffn) + head
+    return families.load(m).forward_flops_per_token(m, seq)
 
 
 def train_flops_per_step(m: dict, tokens: int, seq: int) -> float:
@@ -57,19 +42,8 @@ def decompress_mean_bytes(nb: int, bs: int, world: int) -> float:
 
 
 def param_count(m: dict) -> int:
-    """Parameters the program holds for the configuration: the vocabulary
-    padded to a multiple of 256, norms included, the head tied or not."""
-    d, hq, hkv, hd = m["hidden_size"], m["num_attention_heads"], m["num_key_value_heads"], m["head_dim"]
-    f = m["intermediate_size"]
-    attn = d * hq * hd * 2 + 2 * d * hkv * hd
-    if m.get("num_local_experts"):
-        e = m["num_local_experts"]
-        ffn = e * 3 * d * f + d * e
-    else:
-        ffn = 3 * d * f
-    vocab = -(-m["vocab_size"] // 256) * 256
-    emb = vocab * d * (1 if m["tie_word_embeddings"] else 2)
-    return m["num_hidden_layers"] * (attn + ffn + 2 * d) + d + emb
+    """Parameters the program holds for the configuration."""
+    return families.load(m).param_count(m)
 
 
 def n_buckets(m: dict, bucket_size: int) -> int:

@@ -1,18 +1,10 @@
 """The plain float32 reference of a training step, and its lower-precision control.
 
 Written from the published layer equations and the program's documented
-training semantics, importing nothing of the program:
+training semantics, importing nothing of the program. The model is the
+configuration's family (``families/<family>.py``: weights by the program's
+key schedule, the loss of one row); what is here holds for every family:
 
-* weights are drawn from the seed by the same key schedule as the program's
-  initialiser, so both start from the same point;
-* the decoder is pre-norm (RMSNorm, eps 1e-6), rotary attention (half-split
-  rotation) with grouped kv heads, then either a SwiGLU MLP or a top-k
-  mixture of SwiGLU experts with the program's capacity rule (capacity
-  ``int(1.25 · group · k / E)`` per group of up to 512 tokens, choices in
-  token-major order, later choices dropped), a final RMSNorm and a head over
-  the padded vocabulary (tied to the embedding where the model ties them);
-* the loss is the mean next-token cross entropy plus the MoE load-balance
-  and router-z terms;
 * the local optimizer is SGD with heavy-ball momentum and a constant step
   size, and the exchange is scaled-sign error feedback per bucket of the
   flattened update, averaged over the data-parallel workers in worker order;
@@ -33,15 +25,13 @@ read) or ``bit_order`` (the decoded mean's signs read in reverse order within
 each 32-element word, as a pack and unpack that disagree on bit order).
 
 Gradients are taken one batch row at a time and averaged, which is exact:
-every term of the loss is a mean over whole rows (MoE groups never straddle
-rows).
+every family's loss is a mean over whole rows.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 from typing import Any
 
 import jax
@@ -49,103 +39,12 @@ import jax.numpy as jnp
 from jax import lax
 
 HIGHEST = lax.Precision.HIGHEST
-VOCAB_PAD = 256
-MOE_GROUP = 512
 FP8 = jnp.float8_e4m3fn
 FP8_MAX = 448.0
 
 
-@dataclasses.dataclass(frozen=True)
-class Model:
-    """The sizes and rules the reference needs, as the configuration runs them."""
-
-    num_layers: int
-    d_model: int
-    num_heads: int
-    num_kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab_size: int
-    num_experts: int = 0
-    experts_per_token: int = 0
-    tie_embeddings: bool = False
-    rope_theta: float = 10_000.0
-    param_dtype: str = "float32"
-    capacity_factor: float = 1.25
-    aux_loss_coef: float = 1e-2
-    router_z_coef: float = 1e-3
-
-    @property
-    def padded_vocab(self) -> int:
-        return -(-self.vocab_size // VOCAB_PAD) * VOCAB_PAD
-
-    @property
-    def is_moe(self) -> bool:
-        return self.num_experts > 0
-
-
 # ---------------------------------------------------------------------------
-# weights: the program's key schedule, drawn in float32, stored in param dtype
-# ---------------------------------------------------------------------------
-
-
-def _linear(key, d_in, d_out, dtype):
-    w = jax.random.normal(key, (d_in, d_out), jnp.float32) * (1.0 / math.sqrt(d_in))
-    return {"w": w.astype(dtype)}
-
-
-def _block(key, m: Model, dtype):
-    ks = jax.random.split(key, 8)
-    ka = jax.random.split(ks[0], 4)
-    d, h, kv, hd = m.d_model, m.num_heads, m.num_kv_heads, m.head_dim
-    p = {
-        "norm1": {"scale": jnp.ones((d,), dtype)},
-        "attn": {
-            "wq": _linear(ka[0], d, h * hd, dtype),
-            "wk": _linear(ka[1], d, kv * hd, dtype),
-            "wv": _linear(ka[2], d, kv * hd, dtype),
-            "wo": _linear(ka[3], h * hd, d, dtype),
-        },
-        "norm2": {"scale": jnp.ones((d,), dtype)},
-    }
-    if m.is_moe:
-        km = jax.random.split(ks[2], 4)
-        e, f = m.num_experts, m.d_ff
-        scale_in = 1.0 / jnp.sqrt(d)
-        scale_out = 1.0 / jnp.sqrt(f)
-        p["moe"] = {
-            "router": _linear(km[0], d, e, dtype),
-            "w_in": (jax.random.normal(km[1], (e, d, f), jnp.float32) * scale_in).astype(dtype),
-            "w_out": (jax.random.normal(km[2], (e, f, d), jnp.float32) * scale_out).astype(dtype),
-            "w_gate": (jax.random.normal(km[3], (e, d, f), jnp.float32) * scale_in).astype(dtype),
-        }
-    else:
-        km = jax.random.split(ks[2], 3)
-        p["mlp"] = {
-            "in": _linear(km[0], d, m.d_ff, dtype),
-            "out": _linear(km[1], m.d_ff, d, dtype),
-            "gate": _linear(km[2], d, m.d_ff, dtype),
-        }
-    return p
-
-
-def init_params(m: Model, key) -> dict:
-    dtype = jnp.dtype(m.param_dtype)
-    ks = jax.random.split(key, 5)
-    table = jax.random.normal(ks[0], (m.padded_vocab, m.d_model), jnp.float32) * 0.02
-    layer_keys = jax.random.split(jax.random.fold_in(ks[1], 0), m.num_layers)
-    p = {
-        "embed": {"table": table.astype(dtype)},
-        "final_norm": {"scale": jnp.ones((m.d_model,), dtype)},
-        "blocks": [jax.vmap(lambda k: _block(k, m, dtype))(layer_keys)],
-    }
-    if not m.tie_embeddings:
-        p["head"] = _linear(ks[2], m.d_model, m.padded_vocab, dtype)
-    return p
-
-
-# ---------------------------------------------------------------------------
-# forward and loss of one batch row
+# pieces of a family's forward pass
 # ---------------------------------------------------------------------------
 
 
@@ -191,92 +90,11 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _attention(p, m: Model, h, mm):
-    s = h.shape[0]
-    hq, hkv, hd = m.num_heads, m.num_kv_heads, m.head_dim
-    q = _rope(mm("sd,de->se", h, p["wq"]["w"]).reshape(s, hq, hd), m.rope_theta)
-    k = _rope(mm("sd,de->se", h, p["wk"]["w"]).reshape(s, hkv, hd), m.rope_theta)
-    v = mm("sd,de->se", h, p["wv"]["w"]).reshape(s, hkv, hd)
-    k = jnp.repeat(k, hq // hkv, axis=1)
-    v = jnp.repeat(v, hq // hkv, axis=1)
-    qb = min(s, 1024)
-
-    @jax.checkpoint
-    def block(i):
-        qi = lax.dynamic_slice_in_dim(q, i * qb, qb, axis=0)
-        sc = mm("qhd,khd->hqk", qi, k) / math.sqrt(hd)
-        causal = jnp.arange(s)[None, :] <= (i * qb + jnp.arange(qb))[:, None]
-        pr = jax.nn.softmax(jnp.where(causal[None], sc, -jnp.inf), axis=-1)
-        return mm("hqk,khd->qhd", pr, v)
-
-    out = lax.map(block, jnp.arange(s // qb)).reshape(s, hq * hd)
-    return mm("se,ed->sd", out, p["wo"]["w"])
-
-
-def _swiglu(x, w_gate, w_in, w_out, mm, eq_in, eq_out):
-    return mm(eq_out, jax.nn.silu(mm(eq_in, x, w_gate)) * mm(eq_in, x, w_in), w_out)
-
-
-def _moe(p, m: Model, h, mm):
-    """Top-k experts with per-group capacity; returns (out, aux, z)."""
-    s, d = h.shape
-    g = min(MOE_GROUP, s)
-    x = h.reshape(s // g, g, d)
-    e, k = m.num_experts, m.experts_per_token
-    cap = max(int(m.capacity_factor * g * k / e), k)
-    logits = mm("bsd,de->bse", x, p["router"]["w"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    top, idx = lax.top_k(probs, k)
-    gate = top / jnp.maximum(jnp.sum(top, axis=-1, keepdims=True), 1e-9)
-    onehot = jax.nn.one_hot(idx, e, dtype=jnp.float32)  # (b, g, k, e)
-    flat = onehot.reshape(x.shape[0], g * k, e)
-    before = (jnp.cumsum(flat, axis=1) - flat).reshape(onehot.shape)
-    kept = jnp.sum(before * onehot, axis=-1) < cap  # (b, g, k)
-    weight = jnp.einsum("bsk,bske->bse", gate * kept, onehot, precision=HIGHEST)
-    y = _swiglu(x, p["w_gate"], p["w_in"], p["w_out"], mm, "bsd,edf->bsef", "bsef,efd->bsed")
-    out = jnp.einsum("bse,bsed->bsd", weight, y, precision=HIGHEST)
-    frac = jnp.mean(jnp.sum(onehot, axis=2) / k, axis=1)
-    aux = e * jnp.mean(jnp.sum(frac * jnp.mean(probs, axis=1), axis=-1))
-    z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
-    return out.reshape(s, d), aux, z
-
-
-def row_loss(params, m: Model, tokens, labels, precision: str = "f32"):
-    """Loss of one row of ``tokens``/``labels`` (S,), everything in float32."""
-    mm, act = _matmul(precision), _activation(precision)
-    f32 = lambda t: jax.tree.map(lambda a: a.astype(jnp.float32), t)
-    table = params["embed"]["table"].astype(jnp.float32)
-    x = act(table[tokens])
-    blocks = f32(params["blocks"][0])
-    aux = z = jnp.float32(0.0)
-    for layer in range(m.num_layers):
-        bp = jax.tree.map(lambda a, i=layer: a[i], blocks)
-        x = act(x + _attention(bp["attn"], m, act(_rms(x, bp["norm1"]["scale"])), mm))
-        h = act(_rms(x, bp["norm2"]["scale"]))
-        if m.is_moe:
-            y, a, zz = _moe(bp["moe"], m, h, mm)
-            aux, z = aux + a, z + zz
-        else:
-            mp = bp["mlp"]
-            y = _swiglu(h, mp["gate"]["w"], mp["in"]["w"], mp["out"]["w"], mm, "sd,df->sf", "sf,fd->sd")
-        x = act(x + y)
-    x = act(_rms(x, params["final_norm"]["scale"].astype(jnp.float32)))
-    if m.tie_embeddings:
-        logits = mm("sd,vd->sv", x, table)
-    else:
-        logits = mm("sd,dv->sv", x, params["head"]["w"].astype(jnp.float32))
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ce = -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=-1))
-    total = ce
-    if m.is_moe:
-        total = total + m.aux_loss_coef * aux + m.router_z_coef * z
-    return total
-
-
-def loss_and_grad(m: Model, precision: str = "f32"):
-    """jit of ``(params, tokens (b, S), labels) -> (mean loss, mean grads)``,
-    one row at a time; grads are cast to the parameters' dtype."""
-    vg = jax.value_and_grad(lambda p, t, lab: row_loss(p, m, t, lab, precision))
+def loss_and_grad(family, m, precision: str = "f32"):
+    """jit of ``(params, tokens (b, S), labels) -> (mean loss, mean grads)``
+    by the family's ``row_loss``, one row at a time; grads are cast to the
+    parameters' dtype."""
+    vg = jax.value_and_grad(lambda p, t, lab: family.row_loss(p, m, t, lab, precision))
 
     @jax.jit
     def run(params, tokens, labels):
@@ -350,7 +168,7 @@ def leaf_norms(tree):
 
 
 def run_steps(
-    m: Model,
+    ref,
     weight_key,
     batches: list[dict],
     *,
@@ -366,15 +184,20 @@ def run_steps(
     """Follow the program's first ``steps`` steps from the weights drawn
     from ``weight_key``.
 
+    ``ref`` is ``(family, model)`` as ``cells.reference_model`` gives it:
+    the family module's ``init_params`` and ``row_loss`` at the model's
+    sizes.
+
     ``batches[i]`` is step i's global batch; worker w takes its w-th block of
     rows. Worker w's gradient, momentum and residual live on ``devices[w]``
     (default: all on the first device), so the four-chip cell's reference
     fits.
     """
+    family, m = ref
     devices = devices or [jax.devices()[0]] * workers
-    params = jax.device_put(jax.jit(init_params, static_argnums=0)(m, weight_key), devices[0])
+    params = jax.device_put(jax.jit(family.init_params, static_argnums=0)(m, weight_key), devices[0])
     start = params
-    grad_fn = loss_and_grad(m, precision)
+    grad_fn = loss_and_grad(family, m, precision)
     n_valid, nb = bucket_layout(params, bucket_size)
     bs = bucket_size
     mom = [None] * workers

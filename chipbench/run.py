@@ -13,8 +13,9 @@ With ``--trace 1`` a profiler trace of a few steps takes the window's place
 and the per-layer metrics are read from it.
 
 Once the window has closed and the peak memory has been read, the program's
-state is freed and the float32 reference (``reference.py``) follows the
-same three first steps from the seed; ``check.py`` compares them.
+state is freed and the float32 reference (``reference.py``, with the
+configuration's family from ``families/``) follows the same three first
+steps from the seed; ``check.py`` compares them.
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device``, with ``--trace 1`` also ``breakdown``,

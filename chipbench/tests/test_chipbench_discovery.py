@@ -12,11 +12,16 @@ import sys
 import pytest
 
 import cells
+import counts
+import families
+import tiny
 import tracing
 
 BENCH = cells.benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+FAMILY_API = ("KEYS", "PROGRAM_ONLY_KEYS", "model", "init_params", "row_loss",
+              "forward_flops_per_token", "param_count")
 WIDTH = re.compile(r"hidden_size|intermediate_size|latent|state_size|proj|head_size|_dim$|_rank$|experts_per_tok|expan")
 
 
@@ -47,6 +52,8 @@ def test_configs():
         assert data["reduced"] == c["reduced"]
         assert not any(WIDTH.search(k) for k in c["reduced"]), c["reduced"]
         assert c["source"].startswith("https://")
+        family = families.load(data)
+        assert all(hasattr(family, k) for k in FAMILY_API), family.__name__
 
 
 @pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
@@ -114,3 +121,78 @@ def test_a_new_cell_config_and_metric_are_files_only(tmp_path):
     out = subprocess.run([sys.executable, "-c", probe], cwd=root, capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stderr
     assert out.stdout.split() == ["12", "4096", "7.0"]
+
+
+RELABEL = """\"\"\"The decoder under other published key names.\"\"\"
+from families import decoder
+from families.decoder import PROGRAM_ONLY_KEYS, init_params, row_loss  # noqa: F401
+
+NAMES = {"n_embd": "hidden_size", "n_layer": "num_hidden_layers", "n_head": "num_attention_heads"}
+OURS = {old: new for new, old in NAMES.items()}
+KEYS = {OURS.get(k, k): f for k, f in decoder.KEYS.items()}
+
+
+def _as_decoder(config):
+    return {NAMES.get(k, k): v for k, v in config.items()}
+
+
+def model(config):
+    return decoder.model(_as_decoder(config))
+
+
+def forward_flops_per_token(config, seq):
+    return decoder.forward_flops_per_token(_as_decoder(config), seq)
+
+
+def param_count(config):
+    return decoder.param_count(_as_decoder(config))
+"""
+
+FAMILY_PROBE = """
+import sys
+sys.path[:0] = ["chipbench", "chipbench/tests", {src!r}]
+import jax, numpy as np
+import cells, counts, run, tiny
+c = cells.load("tiny_gpt.ef.w1")
+base = tiny.cell()
+family, model = cells.reference_model(c.config)
+print(family.__name__, model == cells.reference_model(base.config)[1])
+print(cells.program_config(c.config) == cells.program_config(base.config))
+print(counts.train_flops_per_step(c.config, c.tokens_per_step, c.seq)
+      == counts.train_flops_per_step(base.config, base.tokens_per_step, base.seq),
+      counts.param_count(c.config) == counts.param_count(base.config),
+      counts.n_buckets(c.config, 1024))
+pool = run.tokens.batches(7, 3, c.global_rows, c.seq, c.config["vocab_size"])
+got, want = (run.reference_readings(x, 7, pool, jax.devices()) for x in (c, base))
+print(got.losses == want.losses, np.array_equal(got.grad_norms, want.grad_norms),
+      np.array_equal(got.change_norms, want.change_norms))
+"""
+
+
+def test_a_new_family_is_files_only(tmp_path):
+    """A configuration names a family the harness has never seen, a relabelling
+    of ``decoder`` under other published key names: loading the cell, the
+    program's config, the reference's model, the counts and the reference's
+    three steps resolve through it with no edit to an existing file, and
+    read as the decoder under its own names does."""
+    root = tmp_path / "checkout"
+    shutil.copytree(cells.HERE, root / "chipbench", ignore=shutil.ignore_patterns("__pycache__"))
+    src = root / "chipbench"
+    (src / "families" / "gpt_relabel.py").write_text(RELABEL)
+    names = {"hidden_size": "n_embd", "num_hidden_layers": "n_layer", "num_attention_heads": "n_head"}
+    cfg = {names.get(k, k): v for k, v in tiny.config(moe=True).items()}
+    cfg.update(name="tiny_gpt", family="gpt_relabel", source="https://example.org/tiny")
+    (src / "configs" / "tiny_gpt.json").write_text(json.dumps(cfg))
+    (src / "traffic" / "ef.w1.tiny.json").write_text(json.dumps(tiny.cell().traffic))
+    (src / "workloads" / "tiny_gpt.ef.w1.json").write_text(json.dumps({"limits": tiny.LIMITS[True]}))
+    bench = json.loads((cells.CHECKOUT / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "tiny_gpt", "source": cfg["source"], "file": "chipbench/configs/tiny_gpt.json",
+                             "reduced": [], "why": "a family under other key names"})
+    bench["workloads"].append({"name": "tiny_gpt.ef.w1", "config": "tiny_gpt", "traffic": "ef.w1.tiny",
+                               "chips": 1, "why": "small"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    probe = FAMILY_PROBE.format(src=str(cells.CHECKOUT / "src"))
+    out = subprocess.run([sys.executable, "-c", probe], cwd=root, capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert out.stdout.split() == ["families.gpt_relabel", "True", "True", "True", "True",
+                                  str(counts.n_buckets(tiny.config(moe=True), 1024)), "True", "True", "True"]
